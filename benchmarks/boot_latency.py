@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint.store import ArtifactStore
+from repro.core import recompile
 from repro.models import transformer
 from repro.serving.engine import Request, ServingEngine, clear_program_caches
 from repro.serving.sampling import SamplingConfig
@@ -112,12 +113,12 @@ def smoke(cfg, params) -> dict:
     """CI boot-path smoke: deploy + boot the same container twice through
     the control plane; the second boot (fresh program caches, same store)
     must land on the IR rung."""
-    from repro.core import recompile, scheduler
+    from repro.core import scheduler
     from repro.core.invocation import InvocationService
     from repro.serving.service import serving_container
 
     reqs = _requests(cfg, 2, 4)
-    profile = recompile.PORTABLE_CPU
+    profile = recompile.detect_profile()
     boots = []
     with tempfile.TemporaryDirectory() as d:
         store = ArtifactStore(d)
@@ -155,6 +156,7 @@ def smoke(cfg, params) -> dict:
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=3)
     ap.add_argument("--max-new", type=int, default=5)

@@ -30,6 +30,7 @@ import time
 import jax
 
 from repro import configs
+from repro.core import recompile
 from repro.fleet import (SLO, DisaggConfig, DisaggFleetManager, FleetConfig,
                          FleetManager, bursty_trace, materialize)
 from repro.models import transformer
@@ -95,6 +96,7 @@ def run_topology(name: str, cfg, params, reqs, spec, *, horizon: float) -> tuple
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--seed", type=int, default=0)

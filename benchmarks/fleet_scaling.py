@@ -28,6 +28,7 @@ import time
 import jax
 
 from repro import configs
+from repro.core import recompile
 from repro.fleet import (SLO, FleetConfig, FleetManager, bursty_trace,
                          materialize)
 from repro.models import transformer
@@ -80,6 +81,7 @@ def run_policy(name: str, cfg, params, reqs, *, chips, min_replicas,
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--seed", type=int, default=0)

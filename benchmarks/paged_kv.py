@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.core import recompile
 from repro.models import transformer
 from repro.serving.block_manager import pages_for
 from repro.serving.engine import Request, ServingEngine
@@ -135,6 +136,7 @@ def bench_mode(cfg, params, reqs, *, slots: int, max_len: int,
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--concurrency", type=int, default=128,

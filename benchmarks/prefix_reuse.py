@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.core import recompile
 from repro.models import transformer
 from repro.serving.engine import Request, ServingEngine
 
@@ -160,6 +161,7 @@ def bench_fleet(cfg, params, *, smoke: bool, seed: int = 0) -> dict:
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true",

@@ -11,8 +11,11 @@ import argparse
 import sys
 import traceback
 
+from repro.core import recompile
+
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--only", default=None,

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.core import recompile
 from repro.models import transformer
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.sampling import SamplingConfig
@@ -79,6 +80,7 @@ def bench_mode(cfg, params, reqs, *, fused: bool, slots: int, max_len: int,
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--requests", type=int, default=16)

@@ -35,6 +35,7 @@ import numpy as np
 from repro import configs, fleet as fl
 from repro.core import recompile, scheduler
 from repro.core.invocation import InvocationService
+from repro.distributed import sharding as shd
 from repro.models import transformer
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.sampling import SamplingConfig
@@ -110,7 +111,7 @@ def _stream(cfg, params, mesh, reqs, **kw) -> dict:
 
 
 def parity(cfg, params, *, requests: int, max_new: int) -> dict:
-    mesh = jax.make_mesh(MESH, ("data", "model"))
+    mesh = shd.make_mesh(MESH, ("data", "model"))
     paths = {"decode": {},
              "prefill_chunk": dict(page_size=16, kv_pages=9,
                                    prefill_chunk_tokens=16)}
@@ -151,6 +152,7 @@ def throughput_mode(cfg, params, profile, mesh_shape, reqs) -> dict:
 
 
 def main() -> None:
+    recompile.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=6)
@@ -181,8 +183,8 @@ def main() -> None:
           f"(mesh {'x'.join(map(str, MESH))} vs single device)")
 
     reqs = _requests(cfg, n, max_new)
-    base = throughput_mode(cfg, params, recompile.PORTABLE_CPU, None, reqs)
-    shard = throughput_mode(cfg, params, recompile.host_mesh_profile(MESH),
+    base = throughput_mode(cfg, params, recompile.detect_profile(), None, reqs)
+    shard = throughput_mode(cfg, params, recompile.detect_profile(MESH),
                             MESH, reqs)
     ratio = shard["tok_per_chip_s"] / base["tok_per_chip_s"]
     print(f"throughput (roofline-modeled from billed FLOPs): "

@@ -54,19 +54,26 @@ except ImportError:  # pragma: no cover - every pinned env has it
 
 
 def serialize_compiled(compiled) -> bytes:
-    """A ``Compiled`` (from ``jit_fn.lower(...).compile()``) -> bytes."""
+    """A ``Compiled`` (from ``jit_fn.lower(...).compile()``) -> bytes,
+    with the ids of the devices it runs on, in its device order."""
     if not AOT_AVAILABLE:
         raise RuntimeError("jax.experimental.serialize_executable unavailable")
     payload, in_tree, out_tree = _sx.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree))
+    device_ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids))
 
 
 def deserialize_compiled(data: bytes):
-    """bytes -> a callable ``Compiled`` (raises on any malformed input)."""
+    """bytes -> a callable ``Compiled`` loaded on the devices it was
+    compiled for (raises on any malformed input, or when this process
+    lacks one of those devices)."""
     if not AOT_AVAILABLE:
         raise RuntimeError("jax.experimental.serialize_executable unavailable")
-    payload, in_tree, out_tree = pickle.loads(data)
-    return _sx.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(data)
+    by_id = {d.id: d for d in jax.devices()}
+    return _sx.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def runtime_fingerprint() -> dict[str, str]:
@@ -207,9 +214,11 @@ class AotProgram:
         if fp in self.installed:
             try:
                 return exe(*dyn)
-            except Exception as err:
-                # stale installed executable: drop to the cold rung for this
-                # fingerprint only; a bad artifact never takes serving down
+            except (TypeError, ValueError) as err:
+                # stale installed executable — it rejects these arguments'
+                # tree, types or shardings before running: drop to the cold
+                # rung for this fingerprint only. A fault while it runs is
+                # not stale state and propagates.
                 logger.warning("aot %s@%s: installed executable rejected the "
                                "call (%s); re-tracing", self.name, fp, err)
                 self.installed.discard(fp)

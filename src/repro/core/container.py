@@ -33,7 +33,7 @@ __all__ = ["XContainer", "Deployment", "build_mesh"]
 
 def build_mesh(profile: recompile.SystemProfile) -> jax.sharding.Mesh:
     """Materialize the profile's mesh on the current backend's devices."""
-    return jax.make_mesh(profile.mesh_shape, profile.mesh_axes)
+    return shd.make_mesh(profile.mesh_shape, profile.mesh_axes)
 
 
 @dataclasses.dataclass

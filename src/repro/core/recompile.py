@@ -30,6 +30,9 @@ import dataclasses
 import functools
 import hashlib
 import logging
+import math
+import os
+import pathlib
 import time
 from typing import Any, Callable, Mapping
 
@@ -49,6 +52,9 @@ __all__ = [
     "PORTABLE_CPU",
     "CPU_INTERPRET",
     "collective_bytes",
+    "compile_cache_dir",
+    "detect_profile",
+    "enable_compile_cache",
 ]
 
 
@@ -87,7 +93,8 @@ class SystemProfile:
         return hashlib.sha1(repr(self).encode()).hexdigest()[:12]
 
 
-# Assignment-fixed hardware constants: TPU v5e.
+# Per-chip peaks of one TPU v5e (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI (4 links).
 _V5E = dict(
     chip="tpu-v5e",
     peak_flops=197e12,
@@ -199,6 +206,71 @@ def host_mesh_profile(
         mesh_axes=tuple(mesh_axes),
         providers=(),
     )
+
+
+# The one table from what the runtime reports (``device.device_kind``) to
+# the single-chip profile of that device. A kind missing here is an error,
+# never a default: peaks assumed for an unknown chip would be wrong.
+_PROFILE_BY_KIND: dict[str, SystemProfile] = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": PORTABLE_CPU,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _chip_mesh_profile(base: SystemProfile,
+                       mesh_shape: tuple[int, ...]) -> SystemProfile:
+    # no Pallas tier: JAX refuses to partition a Mosaic kernel inside a
+    # program that spans several devices (it would need a shard_map around
+    # each kernel), so a sharded replica serves through the portable tiers
+    geom = "x".join(str(d) for d in mesh_shape)
+    return dataclasses.replace(
+        base, name=f"{base.chip}-mesh-{geom}", chips=math.prod(mesh_shape),
+        mesh_shape=mesh_shape,
+        mesh_axes=("data", "model")[-len(mesh_shape):], providers=())
+
+
+def detect_profile(mesh_shape: tuple[int, ...] | None = None, *,
+                   devices=None) -> SystemProfile:
+    """The profile of the devices this process runs on (``jax.devices()``
+    unless ``devices`` is given), for a replica spanning ``mesh_shape``
+    (one chip when None). Raises ``ValueError`` for a device kind with no
+    entry in the table; a CPU host maps to ``PORTABLE_CPU`` or, for a
+    wider mesh, to ``host_mesh_profile``."""
+    devices = jax.devices() if devices is None else devices
+    kind = devices[0].device_kind
+    base = _PROFILE_BY_KIND.get(kind)
+    if base is None:
+        raise ValueError(
+            f"no SystemProfile for device kind {kind!r} "
+            f"(known: {sorted(_PROFILE_BY_KIND)})")
+    shape = tuple(int(d) for d in mesh_shape) if mesh_shape else (1,)
+    if math.prod(shape) == 1:
+        return base
+    if base.chip == "cpu":
+        return host_mesh_profile(shape)
+    return _chip_mesh_profile(base, shape)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compilation cache
+# ---------------------------------------------------------------------------
+def compile_cache_dir() -> pathlib.Path | None:
+    """Where entry points keep JAX's persistent compilation cache: None
+    when ``JAX_COMPILATION_CACHE_DIR`` is set (JAX reads that itself),
+    else ``.jax_cache/`` at the root of this checkout. The path is part of
+    every cache key, so it is fixed: never a temp name, PID or time."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Turn the persistent compilation cache on at ``compile_cache_dir()``.
+    Entry points call this from ``main``; importing a module never does."""
+    path = compile_cache_dir()
+    if path is not None:
+        jax.config.update("jax_compilation_cache_dir", str(path))
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 # logical axis name -> mesh axis (str | tuple | None)
@@ -83,6 +84,19 @@ def current_rules() -> Rules | None:
 
 def current_mesh() -> jax.sharding.Mesh | None:
     return _STATE.mesh
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
+              devices=None) -> jax.sharding.Mesh:
+    """A mesh whose axes are all ``Auto``: ``constraint`` lowers through
+    ``with_sharding_constraint``, which refuses the ``Explicit`` axes that
+    ``jax.make_mesh`` builds by default. ``devices`` picks the grid's
+    devices (in order); by default ``jax.make_mesh`` chooses them."""
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    grid = np.asarray(devices, dtype=object).reshape(tuple(shape))
+    return jax.sharding.Mesh(grid, tuple(axes), axis_types=types)
 
 
 def mesh_geometry(

@@ -410,7 +410,7 @@ class DisaggFleetManager(FleetManager):
         if fleet.page_size is None:
             raise ValueError("disaggregation requires paged KV: set "
                              "FleetConfig.page_size (and kv_pages)")
-        profile = profile or recompile.PORTABLE_CPU
+        profile = profile or recompile.detect_profile()
         service = InvocationService(scheduler.Cluster(chips=chips))
         spec = None
         if fleet.spec_k > 0:

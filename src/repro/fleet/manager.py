@@ -138,7 +138,7 @@ def replica_bytes_per_chip(cfg, fleet: "FleetConfig",
     # real device stands in for the whole grid
     devs = np.array(
         jax.devices() * int(np.prod(mesh_shape)))[: int(np.prod(mesh_shape))]
-    mesh = jax.sharding.Mesh(devs.reshape(mesh_shape), axes)
+    mesh = shd.make_mesh(mesh_shape, axes, devs)
     with shd.use_rules(dict(shd.RULES_2D), mesh):
         pspecs = shd.param_pspecs(params)
         sspecs = shd.state_pspecs(states)
@@ -949,7 +949,7 @@ class FleetManager:
         mesh_shape = fleet.mesh_shape
         width_decision: dict = {}
         if fleet.mesh_options:
-            base = profile or recompile.PORTABLE_CPU
+            base = profile or recompile.detect_profile()
             per_chip = {tuple(o): replica_bytes_per_chip(cfg, fleet, tuple(o))
                         for o in fleet.mesh_options}
             mesh_shape, reason = choose_replica_width(
@@ -970,9 +970,9 @@ class FleetManager:
             fleet = dataclasses.replace(fleet, mesh_shape=mesh_shape)
         if mesh_shape is not None:
             if profile is None or profile.chips != int(np.prod(mesh_shape)):
-                profile = recompile.host_mesh_profile(tuple(mesh_shape))
+                profile = recompile.detect_profile(tuple(mesh_shape))
         else:
-            profile = profile or recompile.PORTABLE_CPU
+            profile = profile or recompile.detect_profile()
         service = InvocationService(scheduler.Cluster(chips=chips))
         spec = None
         if fleet.spec_k > 0:

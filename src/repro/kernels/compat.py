@@ -1,147 +1,41 @@
-"""JAX/XLA version shim — the adapter between our kernels and the vintage of
-the installed toolchain.
+"""Small adapters between the kernels, the cost model and the installed JAX.
 
-The XaaS portability contract (docs/kernel-portability.md) says a container
-must specialize to *whatever the target platform actually provides*. In
-practice the fastest-moving part of the platform is not the hardware but the
-JAX/Pallas/XLA API surface itself: ``pltpu.CompilerParams`` was named
-``TPUCompilerParams`` for several releases, ``PrefetchScalarGridSpec`` comes
-and goes, and ``Compiled.cost_analysis()`` has returned (a) a dict, (b) a
-one-element list of dicts, and (c) nothing, depending on version and backend.
-
-Every kernel and every cost-model consumer goes through this module instead
-of touching the moving targets directly, so a version bump degrades into a
-*probe failure + tier fallback* (core/hooks.py) rather than an
-``AttributeError`` at trace time deep inside a deployed program — which is
-exactly what happened to the seed's 34 red kernel tests.
+Kernels build their Pallas TPU parameters with ``pltpu.CompilerParams`` and
+``pltpu.PrefetchScalarGridSpec`` directly: the code targets the installed
+JAX only. What stays here is used in more than one place: the VMEM scratch
+allocation every kernel declares, and the normalization of
+``Compiled.cost_analysis()`` that the deployment compiler and the HLO cost
+model both read.
 
 Nothing in here may assume a TPU is attached: all helpers must resolve at
 import time on any XLA host.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Mapping
 
-import jax
-from jax.experimental import pallas as pl  # noqa: F401  (re-exported surface)
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "TPU_COMPILER_PARAMS_CLS",
-    "tpu_compiler_params",
-    "prefetch_scalar_grid_spec",
-    "default_interpret",
     "normalize_cost_analysis",
     "xla_cost_analysis",
     "vmem",
-    "smem_space",
 ]
 
 
-# ---------------------------------------------------------------------------
-# Compiler params: pltpu.CompilerParams (new) vs pltpu.TPUCompilerParams (old)
-# ---------------------------------------------------------------------------
-TPU_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-
-_CP_FIELDS: frozenset[str] = frozenset(
-    inspect.signature(TPU_COMPILER_PARAMS_CLS).parameters
-) if TPU_COMPILER_PARAMS_CLS is not None else frozenset()
-
-
-def tpu_compiler_params(**kwargs: Any):
-    """Build the TPU compiler-params object for ``pl.pallas_call``.
-
-    Accepts the union of fields across versions and silently drops the ones
-    the installed class does not know (e.g. ``dimension_semantics`` moved
-    around between releases); returns ``None`` — which ``pallas_call``
-    accepts as "no params" — when no params class exists at all.
-    """
-    if TPU_COMPILER_PARAMS_CLS is None:
-        return None
-    accepted = {k: v for k, v in kwargs.items() if k in _CP_FIELDS}
-    return TPU_COMPILER_PARAMS_CLS(**accepted)
-
-
-# ---------------------------------------------------------------------------
-# Scalar-prefetch grid spec (SMEM operands, e.g. decode lengths)
-# ---------------------------------------------------------------------------
-def prefetch_scalar_grid_spec(
-    *,
-    num_scalar_prefetch: int,
-    grid: tuple[int, ...],
-    in_specs: list,
-    out_specs,
-    scratch_shapes: list,
-):
-    """``pltpu.PrefetchScalarGridSpec`` where available.
-
-    When a future version drops it, raise ``NotImplementedError`` so the
-    deploy-time probe rejects the tier and dispatch falls back — instead of
-    an AttributeError escaping mid-trace.
-    """
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:
-        raise NotImplementedError(
-            "this jax version has no pltpu.PrefetchScalarGridSpec; "
-            "the pallas decode tier cannot bind")
-    return cls(
-        num_scalar_prefetch=num_scalar_prefetch,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch_shapes,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Memory spaces
-# ---------------------------------------------------------------------------
 def vmem(shape: tuple[int, ...], dtype) -> Any:
-    """A VMEM scratch allocation (``pltpu.VMEM`` across versions)."""
+    """A VMEM scratch allocation."""
     return pltpu.VMEM(shape, dtype)
 
 
-def smem_space() -> Any:
-    """The SMEM memory-space tag for scalar BlockSpecs."""
-    return pltpu.SMEM
-
-
-# ---------------------------------------------------------------------------
-# Interpret-mode default
-# ---------------------------------------------------------------------------
-def default_interpret() -> bool:
-    """Pallas TPU kernels interpret (pure-JAX emulation) off TPU metal."""
-    return jax.default_backend() != "tpu"
-
-
-# ---------------------------------------------------------------------------
-# XLA cost_analysis normalization
-# ---------------------------------------------------------------------------
 def normalize_cost_analysis(raw: Any) -> dict:
-    """Normalize ``jax.stages.Compiled.cost_analysis()`` output to one dict.
-
-    Formats seen in the wild:
-      * ``dict`` — current jax;
-      * ``[dict]`` — one dict per partition, older jax (calling ``dict()`` on
-        it iterates the inner dict's KEYS and dies with "dictionary update
-        sequence element #0 has length 7");
-      * ``None`` / ``[]`` — backends without a cost model.
-    """
+    """``jax.stages.Compiled.cost_analysis()`` output as one dict: the
+    installed JAX returns a dict, or ``None`` on a backend without a cost
+    model."""
     if raw is None:
         return {}
     if isinstance(raw, Mapping):
         return dict(raw)
-    if isinstance(raw, (list, tuple)):
-        if not raw:
-            return {}
-        first = raw[0]
-        if isinstance(first, Mapping):
-            return dict(first)
-        # a genuine sequence of (key, value) pairs
-        if isinstance(first, (list, tuple)) and len(first) == 2:
-            return dict(raw)
     raise TypeError(
         f"unrecognized cost_analysis() format: {type(raw).__name__}")
 

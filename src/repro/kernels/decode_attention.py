@@ -9,7 +9,8 @@ cache HBM->VMEM. The kernel therefore:
     arithmetic-intensity multiplier, which is the reason GQA exists),
   * walks the cache in (BK, D) blocks along a sequential grid axis with
     online-softmax scratch (same recurrence as prefill flash),
-  * reads per-row valid `lengths` from SMEM and masks the tail block, and
+  * reads per-row valid `lengths` (scalar-prefetched into SMEM) and masks
+    the tail block, and
     skips blocks entirely past `length` (pl.when — no HBM traffic for the
     unused cache suffix of short rows... the *block-level* early exit).
 
@@ -23,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -31,7 +33,7 @@ DEFAULT_BK = 256
 
 
 def _decode_kernel(
-    len_ref,  # SMEM (1,)   valid length for this batch row
+    len_ref,  # SMEM (B,)   valid lengths, scalar-prefetched
     q_ref,  # (1, 1, G, D)
     k_ref,  # (1, 1, BK, D)
     v_ref,
@@ -41,7 +43,7 @@ def _decode_kernel(
 ):
     j = pl.program_id(2)
     nj = pl.num_programs(2)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -99,11 +101,9 @@ def decode_attention(
     scale: float | None = None,
     logit_softcap: float | None = None,
     block_k: int = DEFAULT_BK,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Drop-in for the `decode_attention` hook ABI (see kernels/ref.py)."""
-    if interpret is None:
-        interpret = compat.default_interpret()
     b, hq, d = q.shape
     s, hkv = k_cache.shape[1], k_cache.shape[2]
     assert hq % hkv == 0
@@ -129,18 +129,16 @@ def decode_attention(
 
     out = pl.pallas_call(
         kernel,
-        grid_spec=compat.prefetch_scalar_grid_spec(
-            num_scalar_prefetch=0,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # lengths land in SMEM
             grid=grid,
             in_specs=[
-                pl.BlockSpec(
-                    (1,), lambda b_, h, j: (b_,),
-                    memory_space=compat.smem_space()),
-                pl.BlockSpec((1, 1, g, d), lambda b_, h, j: (b_, h, 0, 0)),
-                pl.BlockSpec((1, 1, bk, d), lambda b_, h, j: (b_, h, j, 0)),
-                pl.BlockSpec((1, 1, bk, d), lambda b_, h, j: (b_, h, j, 0)),
+                pl.BlockSpec((1, 1, g, d), lambda b_, h, j, ln: (b_, h, 0, 0)),
+                pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, ln: (b_, h, j, 0)),
+                pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, ln: (b_, h, j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h, j: (b_, h, 0, 0)),
+            out_specs=pl.BlockSpec(
+                (1, 1, g, d), lambda b_, h, j, ln: (b_, h, 0, 0)),
             scratch_shapes=[
                 compat.vmem((g,), jnp.float32),
                 compat.vmem((g,), jnp.float32),
@@ -148,7 +146,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

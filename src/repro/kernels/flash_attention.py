@@ -27,6 +27,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -127,11 +128,9 @@ def flash_attention(
     logit_softcap: float | None = None,
     block_q: int = DEFAULT_BQ,
     block_k: int = DEFAULT_BK,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Drop-in for the `attention` hook ABI (see kernels/ref.py)."""
-    if interpret is None:
-        interpret = compat.default_interpret()
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     assert hq % hkv == 0
@@ -168,7 +167,7 @@ def flash_attention(
             compat.vmem((bq,), jnp.float32),
             compat.vmem((bq, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -62,11 +63,9 @@ def moe_swiglu_hidden(
     block_c: int = 128,
     block_f: int = 128,
     block_k: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """h = silu(x @ w_gate) * (x @ w_up), grouped over experts. (E, C, F)."""
-    if interpret is None:
-        interpret = compat.default_interpret()
     e, c, d = x.shape
     f = w_gate.shape[-1]
     bc = min(block_c, c)
@@ -103,7 +102,7 @@ def moe_swiglu_hidden(
             compat.vmem((bc, bf), jnp.float32),
             compat.vmem((bc, bf), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -113,7 +112,7 @@ def moe_swiglu_hidden(
 
 
 def moe_mlp(expert_inputs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-            w_down: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+            w_down: jax.Array, *, interpret: bool = False) -> jax.Array:
     """Full expert FFN matching the `moe_mlp` hook ABI: the fused SwiGLU
     kernel + a grouped down-projection einsum."""
     h = moe_swiglu_hidden(expert_inputs, w_gate, w_up, interpret=interpret)

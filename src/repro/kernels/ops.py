@@ -20,11 +20,10 @@ matrix in docs/kernel-portability.md):
 Priorities: pallas-tpu (20) > pallas-interpret (15) > xla-blocked (10) >
 portable reference (0).
 
-Each Pallas-backed tier registers a *probe* (core/hooks.py): a tiny
+Each Pallas-backed tier registers a *probe* (core/hooks.py): a small
 candidate kernel compiled and run exactly the way the tier would execute on
-the target. ``bind(profile, probe=True)`` rejects tiers whose probe fails —
-so a JAX API-vintage mismatch (kernels/compat.py) degrades to the next tier
-instead of crashing a deployed program mid-trace.
+the target. ``bind(profile, probe=True)`` rejects tiers whose probe fails,
+and the rejection is recorded in the binding's manifest.
 """
 from __future__ import annotations
 
@@ -293,12 +292,12 @@ def interpret_moe_mlp(expert_inputs, w_gate, w_up, w_down):
 
 
 # ---------------------------------------------------------------------------
-# Deploy-time probes: compile + run a TINY candidate kernel per tier, in the
-# mode the tier would actually execute on the target. Failures (e.g. a
-# pltpu API rename the shim cannot paper over) reject the tier at bind time.
-# Probe tiles are (8, 128) — the f32 minimum Mosaic tile — so a compiled-
-# Mosaic probe on TPU metal sees the same tile constraints the full-size
-# kernels do and cannot be falsely rejected for sub-minimum blocks.
+# Deploy-time probes: compile + run a small candidate kernel per tier, in the
+# mode the tier executes on the target; a failure rejects the tier at bind
+# time. Probe tiles are (8, 128) — the f32 minimum Mosaic tile — and the
+# decode probes use B >= 2 rows and Hkv >= 2 KV heads, so per-row SMEM
+# operands and the KV-head blocking meet the same Mosaic layout rules they
+# meet at serving widths.
 # ---------------------------------------------------------------------------
 def _probe_args_attn():
     z = jnp.zeros((1, 8, 8, 128), jnp.float32)
@@ -317,20 +316,24 @@ def _probe_flash(interpret):
 
 def _probe_decode(interpret):
     def probe(profile):
-        q = jnp.zeros((1, 8, 128), jnp.float32)
-        kc = jnp.zeros((1, 8, 1, 128), jnp.float32)
+        q = jnp.zeros((2, 8, 128), jnp.float32)
+        kc = jnp.zeros((2, 16, 2, 128), jnp.float32)
+        lengths = jnp.array([5, 16], jnp.int32)
         _dec_pallas.decode_attention(
-            q, kc, kc, block_k=8, interpret=interpret).block_until_ready()
+            q, kc, kc, lengths=lengths, block_k=8, interpret=interpret
+        ).block_until_ready()
     return probe
 
 
 def _probe_paged_decode(interpret):
     def probe(profile):
-        q = jnp.zeros((1, 8, 128), jnp.float32)
-        pool = jnp.zeros((2, 8, 1, 128), jnp.float32)
-        bt = jnp.ones((1, 1), jnp.int32)
+        q = jnp.zeros((2, 8, 128), jnp.float32)
+        pool = jnp.zeros((5, 8, 2, 128), jnp.float32)
+        bt = jnp.array([[1, 2], [3, 4]], jnp.int32)
+        lengths = jnp.array([5, 16], jnp.int32)
         _paged_pallas.paged_decode_attention(
-            q, pool, pool, bt, interpret=interpret).block_until_ready()
+            q, pool, pool, bt, lengths=lengths, interpret=interpret
+        ).block_until_ready()
     return probe
 
 
@@ -358,10 +361,9 @@ def _probe_blocked(profile):
     blocked_attention(q, k, v, block_q=8, block_k=8).block_until_ready()
 
 
-# interpret=None lets each kernel pick its own execution mode for the target
-# backend (compiled Mosaic on TPU metal, interpreter elsewhere) — the probe
-# then exercises exactly the path the bound tier will take.
-_TPU_MODE = None
+# the pallas-tpu tier always compiles through Mosaic; pallas-interpret is
+# the only tier that runs the kernels in the interpreter
+_TPU_MODE = False
 _INTERP_MODE = True
 
 

@@ -11,15 +11,14 @@ table from SMEM — so the DMA engine streams exactly the pages the row owns,
 in logical order, and the online-softmax recurrence is unchanged from the
 contiguous kernel.
 
-Grid: (B, Hkv, N) with N = pool pages per row (block-table width); logical
-page j covers absolute positions [j*page, (j+1)*page). Pages entirely past
-a row's ``length`` are skipped block-level (``pl.when`` — no HBM traffic for
-the unallocated suffix, whose table entries point at the reserved null page
-0). Window (local attention) masks positions < length - window.
-
-TPU-metal note: the page size is the kv block size, so compiled-Mosaic use
-wants page >= 8 (the f32 min sublane tile); the interpret tier has no such
-constraint and is what CPU CI exercises.
+Grid: (B, N) with N = pool pages per row (block-table width); logical page
+j covers absolute positions [j*page, (j+1)*page). One grid step streams a
+whole ``(page, Hkv, D)`` page: its last two dims are the pool's own, which
+is what Mosaic requires of a block whose Hkv is below the sublane tile, and
+each KV head's (page, D) slice is read out of it in VMEM. Pages entirely
+past a row's ``length`` are skipped block-level (``pl.when`` — no compute
+for the unallocated suffix, whose table entries point at the reserved null
+page 0). Window (local attention) masks positions < length - window.
 """
 from __future__ import annotations
 
@@ -28,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -37,17 +37,17 @@ _NEG_INF = -1e30
 def _paged_decode_kernel(
     len_ref,  # SMEM (B,)    valid lengths, scalar-prefetched
     bt_ref,  # SMEM (B, N)   block tables, scalar-prefetched
-    q_ref,  # (1, 1, G, D)
-    k_ref,  # (1, page, 1, D)  the physical page the index map gathered
+    q_ref,  # (1, Hkv, G, D)
+    k_ref,  # (1, page, Hkv, D)  the physical page the index map gathered
     v_ref,
-    o_ref,  # (1, 1, G, D)
-    m_scr, l_scr, acc_scr,
+    o_ref,  # (1, Hkv, G, D)
+    m_scr, l_scr, acc_scr,  # (Hkv, G), (Hkv, G), (Hkv, G, D)
     *, scale: float, window: int | None, softcap: float | None, page: int,
+    hkv: int,
 ):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    length = len_ref[b]
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -61,35 +61,36 @@ def _paged_decode_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, page)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < length
-        if window is not None:
-            mask &= kpos >= length - window
-        s = jnp.where(mask, s, _NEG_INF)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)  # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, page)
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos < length
+            if window is not None:
+                mask &= kpos >= length - window
+            s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_cur
+            m_prev = m_scr[h]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur[:, None])
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1)
+            acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_cur
 
     @pl.when(j == nj - 1)
     def _finish():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l[..., None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -105,11 +106,9 @@ def paged_decode_attention(
     window: int | None = None,
     scale: float | None = None,
     logit_softcap: float | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Drop-in for the `paged_decode_attention` hook ABI (kernels/ref.py)."""
-    if interpret is None:
-        interpret = compat.default_interpret()
     b, hq, d = q.shape
     page, hkv = k_pool.shape[1], k_pool.shape[2]
     n = block_tables.shape[1]
@@ -120,39 +119,38 @@ def paged_decode_attention(
         lengths = jnp.full((b,), n * page, jnp.int32)
 
     qt = q.reshape(b, hkv, g, d)
-    grid = (b, hkv, n)
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window,
-        softcap=logit_softcap, page=page)
+        softcap=logit_softcap, page=page, hkv=hkv)
 
     out = pl.pallas_call(
         kernel,
-        grid_spec=compat.prefetch_scalar_grid_spec(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # lengths + block tables land in SMEM
-            grid=grid,
+            grid=(b, n),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 1, g, d), lambda b_, h, j, lens, bt: (b_, h, 0, 0)),
+                    (1, hkv, g, d), lambda b_, j, lens, bt: (b_, 0, 0, 0)),
                 # the paging indirection: logical page j of row b_ is the
                 # physical pool page the prefetched table names
                 pl.BlockSpec(
-                    (1, page, 1, d),
-                    lambda b_, h, j, lens, bt: (bt[b_, j], 0, h, 0)),
+                    (1, page, hkv, d),
+                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0, 0)),
                 pl.BlockSpec(
-                    (1, page, 1, d),
-                    lambda b_, h, j, lens, bt: (bt[b_, j], 0, h, 0)),
+                    (1, page, hkv, d),
+                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, g, d), lambda b_, h, j, lens, bt: (b_, h, 0, 0)),
+                (1, hkv, g, d), lambda b_, j, lens, bt: (b_, 0, 0, 0)),
             scratch_shapes=[
-                compat.vmem((g,), jnp.float32),
-                compat.vmem((g,), jnp.float32),
-                compat.vmem((g, d), jnp.float32),
+                compat.vmem((hkv, g), jnp.float32),
+                compat.vmem((hkv, g), jnp.float32),
+                compat.vmem((hkv, g, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), qt,

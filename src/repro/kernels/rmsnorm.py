@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import compat
-
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)  # (BR, D)
@@ -32,10 +30,8 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool | None = None) -> jax.Array:
+            block_rows: int = 256, interpret: bool = False) -> jax.Array:
     """Drop-in for the `rmsnorm` hook ABI (see kernels/ref.py)."""
-    if interpret is None:
-        interpret = compat.default_interpret()
     lead = x.shape[:-1]
     d = x.shape[-1]
     rows = 1

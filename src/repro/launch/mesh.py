@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.distributed import sharding as shd
+
 __all__ = ["make_production_mesh", "mesh_axis_sizes", "batch_axes"]
 
 
@@ -16,21 +18,19 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod: (pod=2, data=16, model=16) = 512 chips; `pod` crosses DCN."""
     import math
 
-    import numpy as np
-
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return shd.make_mesh(shape, axes)
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run via launch/dryrun.py (it forces 512 stand-in host devices)")
     # more devices than the mesh needs (e.g. single-pod mesh in a 512-device
     # dry-run process): take a prefix slice
-    return jax.sharding.Mesh(np.array(devices[:n]).reshape(shape), axes)
+    return shd.make_mesh(shape, axes, devices[:n])
 
 
 def mesh_axis_sizes(mesh: jax.sharding.Mesh) -> dict[str, int]:

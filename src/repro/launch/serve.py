@@ -70,15 +70,13 @@ def run(arch_id: str, *, requests: int = 8, max_new: int = 16,
                           draft_arch=draft_arch)
 
     # control plane: schedule chips, deploy the container, boot the engine
-    profile = recompile.PORTABLE_CPU
-    if mesh is not None and int(np.prod(mesh)) > 1:
-        need = int(np.prod(mesh))
-        if jax.device_count() < need:
-            raise SystemExit(
-                f"--mesh {'x'.join(map(str, mesh))} needs {need} devices but "
-                f"only {jax.device_count()} visible; on CPU set "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-        profile = recompile.host_mesh_profile(tuple(mesh))
+    need = int(np.prod(mesh)) if mesh is not None else 1
+    if jax.device_count() < need:
+        raise SystemExit(
+            f"--mesh {'x'.join(map(str, mesh))} needs {need} devices but "
+            f"only {jax.device_count()} visible; on CPU set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
+    profile = recompile.detect_profile(mesh)
     cont = serving_container(cfg, params, slots=slots, max_len=max_len,
                              prompt_buckets=(32, 64, 128), fused=fused,
                              sync_every=sync_every,
@@ -385,6 +383,7 @@ def main() -> None:
                     help="disagg decode pool size bounds")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    recompile.enable_compile_cache()
     if args.fleet:
         run_fleet(args.arch, trace_kind=args.trace, smoke=args.smoke,
                   seed=args.seed, chips=args.chips,

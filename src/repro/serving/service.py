@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import container as xcontainer
+from repro.distributed import sharding as shd
 from repro.models import transformer
 from repro.serving.engine import ServingEngine
 
@@ -104,7 +105,7 @@ def serving_container(
                 mesh = deployment.mesh
             else:
                 axes = ("data", "model")[-len(mesh_shape):]
-                mesh = jax.make_mesh(tuple(mesh_shape), axes)
+                mesh = shd.make_mesh(tuple(mesh_shape), axes)
             eng_rules = rules if rules is not None else deployment.rules
         return ServingEngine(
             cfg, params, slots=slots, max_len=max_len,

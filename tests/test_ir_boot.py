@@ -19,6 +19,7 @@ from repro import configs
 from repro.checkpoint.store import ArtifactStore
 from repro.core import aot, container as xc, hooks, recompile, scheduler
 from repro.core.invocation import InvocationService
+from repro.distributed import sharding as shd
 from repro.models import transformer
 from repro.serving.engine import (Request, ServingEngine,
                                   clear_program_caches)
@@ -155,7 +156,7 @@ def test_stale_mesh_falls_through_to_cold(tmp_path):
     assert store.keys()
 
     clear_program_caches()
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = shd.make_mesh((1, 2), ("data", "model"))
     e = ServingEngine(cfg, params, artifact_store=store, mesh=mesh, **GEOM)
     assert e.boot_path_preview() == "cold"
     m = e.warmup()

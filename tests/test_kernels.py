@@ -81,6 +81,42 @@ def test_decode_attention_vs_ref(b, s, hq, hkv, d, window, dtype):
 
 
 # ---------------------------------------------------------------------------
+# paged decode attention (Pallas, interpret) vs oracle
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "hq,hkv,d,page,window,softcap",
+    [
+        (4, 2, 32, 8, None, None),   # GQA, all KV heads in one block
+        (4, 1, 32, 8, None, None),   # MQA
+        (14, 2, 64, 16, None, None),  # qwen2-0.5b head geometry
+        (4, 2, 32, 8, 12, None),     # sliding window
+        (4, 2, 32, 8, None, 30.0),   # logit softcap
+    ],
+)
+def test_paged_decode_attention_vs_ref(hq, hkv, d, page, window, softcap,
+                                       dtype):
+    from repro.kernels import paged_attention as pg
+
+    n, b = 4, 3
+    ks = jax.random.split(jax.random.fold_in(KEY, hq * 7 + page), 3)
+    q = jax.random.normal(ks[0], (b, hq, d), jnp.float32).astype(dtype)
+    shape = (1 + b * n, page, hkv, d)
+    kp = jax.random.normal(ks[1], shape, jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
+    # rows own disjoint, shuffled pages; page 0 is the null page
+    perm = np.random.default_rng(hq + page).permutation(b * n) + 1
+    bt = jnp.asarray(perm.reshape(b, n), jnp.int32)
+    lengths = jnp.asarray([1, page * 2 + 3, page * n], jnp.int32)
+    kw = dict(lengths=lengths, window=window, logit_softcap=softcap)
+    want = ref.paged_decode_attention(q, kp, vp, bt, **kw)
+    got = pg.paged_decode_attention(q, kp, vp, bt, interpret=True, **kw)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=TOL[dtype], rtol=TOL[dtype])
+
+
+# ---------------------------------------------------------------------------
 # blocked (xla) attention vs oracle — including the MLA dv != dq case
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(

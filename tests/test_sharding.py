@@ -149,7 +149,7 @@ def test_sharded_stream_parity(arch, path):
     params = transformer.init_model(jax.random.key(0), cfg)
     kw = _engine_kw(path)
     ref = _stream(cfg, params, None, **kw)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = shd.make_mesh((1, 2), ("data", "model"))
     got = _stream(cfg, params, mesh, **kw)
     assert got == ref, f"{arch}/{path}: sharded stream diverged"
 
@@ -162,7 +162,7 @@ def test_sharded_stream_wide_mesh_completes():
     on the near-uniform logits of a random-init smoke model."""
     cfg = configs.get_config("deepseek-v3-671b-smoke")
     params = transformer.init_model(jax.random.key(0), cfg)
-    got = _stream(cfg, params, jax.make_mesh((1, 4), ("data", "model")),
+    got = _stream(cfg, params, shd.make_mesh((1, 4), ("data", "model")),
                   max_new=6)
     assert len(got) == 6
     assert all(0 <= t < cfg.vocab_size for t in got)
@@ -178,7 +178,7 @@ def test_data_axis_mesh_rejected():
     params = transformer.init_model(jax.random.key(0), cfg)
     with pytest.raises(ValueError, match="data axis"):
         ServingEngine(cfg, params, slots=2, max_len=32,
-                      mesh=jax.make_mesh((2, 1), ("data", "model")))
+                      mesh=shd.make_mesh((2, 1), ("data", "model")))
 
 
 @needs_2dev
@@ -190,7 +190,7 @@ def test_expert_weights_and_kv_pool_sharded():
 
     cfg = configs.get_config("deepseek-v3-671b-smoke")
     params = transformer.init_model(jax.random.key(0), cfg)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = shd.make_mesh((1, 2), ("data", "model"))
     eng = ServingEngine(cfg, params, slots=2, max_len=64,
                         prompt_buckets=(16, 64), mesh=mesh,
                         page_size=16, kv_pages=9)

@@ -1,0 +1,7 @@
+"""Per cent of the time with a request in flight in which the device ran
+no operation (trace), in a cell that serves a backlog."""
+from bench.metrics import common
+
+
+def read(ctx):
+    return common.idle_share(ctx)

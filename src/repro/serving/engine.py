@@ -32,6 +32,11 @@ Design (vLLM-shape, JAX-native):
 ``fused=False`` keeps the legacy host-loop step (B scalar ``sample`` calls +
 per-token ``device_get`` + per-slot length sync) as the "before" reference for
 ``benchmarks/serving_throughput.py``.
+
+Each ``step()`` writes host spans named ``serve.*`` (admit, prefix, prefill,
+decode, flush, sync) into the profiler's trace, on the device planes' clock,
+when a profiler records; ``docs/paged-kv.md`` lists them and the step's
+counters in ``stats``.
 """
 from __future__ import annotations
 
@@ -65,6 +70,17 @@ __all__ = ["Request", "RequestResult", "HandoffPacket", "ServingEngine",
 logger = logging.getLogger(__name__)
 
 _NO_LIMIT = 1 << 30
+
+# a host span of the serving step: recorded only while a profiler traces,
+# about half a microsecond otherwise
+_span = jax.profiler.TraceAnnotation
+
+
+def _fetch(x):
+    """Blocking device->host copy: the time the host waits for the device,
+    as a ``serve.sync`` span."""
+    with _span("serve.sync"):
+        return jax.device_get(x)
 
 
 @dataclasses.dataclass
@@ -847,17 +863,17 @@ class ServingEngine:
             "prefills": 0,          # requests prefilled
             "prefill_calls": 0,     # batched prefill program executions
             "prefill_tokens": 0,    # padded token-positions run through prefill
-            "decode_steps": 0,
+            "prefill_real_tokens": 0,  # prompt positions among them
+            "decode_steps": 0,      # decode programs run
+            "decode_rows": 0,       # armed rows summed over those programs
             "retired": 0,
             "host_syncs_decode": 0,  # blocking device->host syncs on the decode path
-            "host_syncs_admit": 0,   # blocking syncs during admission
             "unserved": 0,
             "prefix_hits": 0,        # admissions that reused a cached prefix
             "prefix_misses": 0,      # cache enabled but no usable prefix
             "prefix_hit_tokens": 0,  # prompt tokens restored instead of prefilled
             # ---- speculative decoding telemetry ----
             "spec_steps": 0,         # speculative verify program executions
-            "spec_slot_steps": 0,    # active slots summed over those steps
             "spec_drafted": 0,       # draft tokens offered for verification
             "spec_accepted": 0,      # draft tokens accepted (and emitted)
             "spec_emitted": 0,       # total tokens emitted by spec steps
@@ -874,10 +890,6 @@ class ServingEngine:
             "handoffs_out": 0,         # finished prefills exported as packets
             "handoffs_in": 0,          # packets installed into decode slots
             "handoff_sha_rejects": 0,  # packets refused on page-sha mismatch
-            # ---- latency telemetry (real wall-clock; per-request values
-            # live in RequestResult.ttft_s / decode_s) ----
-            "ttft_sum_s": 0.0,
-            "decode_sum_s": 0.0,
         }
 
         # ---- compiled programs: shared per (cfg, geometry, tier-set) so
@@ -1306,11 +1318,10 @@ class ServingEngine:
                 groups.setdefault(
                     _bucket(suffix, self.prompt_buckets), []).append(e)
             for sc, es in groups.items():
-                if self.fused:
-                    self._admit_group(sc, es, free)
-                else:
-                    for e in es:
-                        self._admit_group(sc, [e], free)
+                for batch in ([es] if self.fused else [[e] for e in es]):
+                    with _span("serve.prefill", rows=len(batch),
+                               npad=_pow2(len(batch)), cw=sc):
+                        self._admit_group(sc, batch, free)
 
     def _lookup_prefix(self, req: Request):
         """-> (match, start): the longest usable cached prefix and the pin
@@ -1320,7 +1331,8 @@ class ServingEngine:
         prompt = np.asarray(req.prompt, np.int32)
         # always prefill at least the last prompt token: its logits seed the
         # first sampled token
-        match = self.prefix_cache.match(prompt, limit=prompt.shape[-1] - 1)
+        with _span("serve.prefix"):
+            match = self.prefix_cache.match(prompt, limit=prompt.shape[-1] - 1)
         if match.usable <= 0:
             self.stats["prefix_misses"] += 1
             return None, 0
@@ -1349,32 +1361,34 @@ class ServingEngine:
             if start > 0:
                 # restore re-walks the radix tree itself: `match` may be
                 # stale if an earlier group's insert split a node on its path
-                bstates = self.prefix_cache.restore(prompt, bstates, i, start)
+                with _span("serve.prefix"):
+                    bstates = self.prefix_cache.restore(
+                        prompt, bstates, i, start)
         logits, bstates, _ = self._prefill_chunk(
             self.params, jnp.asarray(batch), bstates,
             jnp.asarray(starts), jnp.asarray(lens))
         self.stats["prefill_calls"] += 1
         self.stats["prefills"] += n
         self.stats["prefill_tokens"] += npad * sc
+        self.stats["prefill_real_tokens"] += int((lens - starts)[:n].sum())
 
         pad_cfg = [e[0].sampling for e in entries] \
             + [SamplingConfig()] * (npad - n)
         self.rng, sub = jax.random.split(self.rng)
         first = self._sample_first(sub, logits, SamplingParams.from_configs(pad_cfg))
-        first_host = np.asarray(jax.device_get(first))
-        self.stats["host_syncs_admit"] += 1
+        first_host = np.asarray(_fetch(first))
         now = time.perf_counter()
 
         for i, (req, match, start) in enumerate(entries):
             ttft = now - self._submit_s.pop(req.request_id, now)
-            self.stats["ttft_sum_s"] += ttft
             plen = int(np.asarray(req.prompt).shape[-1])
             pin = None
             if self.prefix_cache is not None:
                 # donate the full-prompt state back to the radix tree and
                 # swap the admission pin for one on the (deeper) donated node
-                pin = self.prefix_cache.acquire(
-                    self.prefix_cache.insert(req.prompt, bstates, i, match))
+                with _span("serve.prefix"):
+                    pin = self.prefix_cache.acquire(
+                        self.prefix_cache.insert(req.prompt, bstates, i, match))
                 if match is not None:
                     self.prefix_cache.release(match.path[-1][0])
             # prefill token + decode steps until the cache fills at max_len
@@ -1441,8 +1455,10 @@ class ServingEngine:
             req = self.queue.popleft()
             prompt = np.asarray(req.prompt, np.int32).reshape(-1)
             plen = int(prompt.shape[-1])
-            match = (self.prefix_cache.match(prompt, limit=plen - 1)
-                     if self.prefix_cache is not None else None)
+            match = None
+            if self.prefix_cache is not None:
+                with _span("serve.prefix"):
+                    match = self.prefix_cache.match(prompt, limit=plen - 1)
             start = match.usable if match is not None else 0
             # budget: fresh pages past the shared FULL pages — a shared
             # PARTIAL tail page is copied (not aliased) right here, so the
@@ -1456,10 +1472,12 @@ class ServingEngine:
                 # means nothing will ever free pages for us.
                 idle = not kept and all(r is None for r in self.active)
                 if self.prefix_cache is not None:
-                    self.prefix_cache.reclaim(need + bm.watermark_pages)
-                    # eviction may have dropped the matched branch: re-match
-                    # before touching its page refs
-                    match = self.prefix_cache.match(prompt, limit=plen - 1)
+                    with _span("serve.prefix"):
+                        self.prefix_cache.reclaim(need + bm.watermark_pages)
+                        # eviction may have dropped the matched branch:
+                        # re-match before touching its page refs
+                        match = self.prefix_cache.match(prompt,
+                                                        limit=plen - 1)
                     start = match.usable
                     need = pages_for(plen, ps) - start // ps
                 if not bm.can_alloc(need, respect_watermark=not idle):
@@ -1507,16 +1525,24 @@ class ServingEngine:
         rows = sorted(self._admitting)
         if not rows:
             return
-        ps = self.page_size
         remaining = max(self._admitting[s]["plen"] - self._admitting[s]["pos"]
                         for s in rows)
         cw = _bucket(min(remaining, self._chunk_cap), self._chunk_widths)
+        npad = _pow2(len(rows))
+        with _span("serve.prefill", rows=len(rows), npad=npad, cw=cw):
+            self._run_chunk(rows, cw, npad)
+
+    def _run_chunk(self, rows: list[int], cw: int, npad: int) -> None:
+        """One chunk of ``cw`` positions for the mid-prefill slots ``rows``,
+        batched to ``npad`` rows, then first tokens and arming for the
+        prompts it completes."""
+        ps = self.page_size
         n = len(rows)
-        npad = _pow2(n)
         toks = np.zeros((npad, cw), np.int32)
         starts = np.zeros((npad,), np.int32)
         lens = np.ones((npad,), np.int32)  # pad rows: 1 pos on the null page
         bt = np.zeros((npad, self.max_blocks), np.int32)
+        real = 0
         for i, s in enumerate(rows):
             st = self._admitting[s]
             w = min(cw, st["plen"] - st["pos"])
@@ -1525,6 +1551,7 @@ class ServingEngine:
             # so the slot survives)
             self._prepare_write(s, st["pos"], st["pos"] + w)
             toks[i, :w] = st["prompt"][st["pos"]: st["pos"] + w]
+            real += w
             starts[i] = st["pos"]
             lens[i] = st["pos"] + w
             bt[i, : len(self._pages[s])] = self._pages[s]
@@ -1534,6 +1561,7 @@ class ServingEngine:
         self.stats["prefill_calls"] += 1
         self.stats["chunk_prefill_calls"] += 1
         self.stats["prefill_tokens"] += npad * cw
+        self.stats["prefill_real_tokens"] += real
 
         fin = [i for i, s in enumerate(rows)
                if int(lens[i]) >= self._admitting[s]["plen"]]
@@ -1546,8 +1574,7 @@ class ServingEngine:
         self.rng, sub = jax.random.split(self.rng)
         first = self._sample_first(sub, logits,
                                    SamplingParams.from_configs(pad_cfg))
-        first_host = np.asarray(jax.device_get(first))
-        self.stats["host_syncs_admit"] += 1
+        first_host = np.asarray(_fetch(first))
         now = time.perf_counter()
         for i, s in enumerate(rows):
             st = self._admitting[s]
@@ -1561,10 +1588,10 @@ class ServingEngine:
             tok = int(first_host[i])
             self.stats["prefills"] += 1
             ttft = now - self._submit_s.pop(req.request_id, now)
-            self.stats["ttft_sum_s"] += ttft
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(
-                    st["prompt"], self._pages[s][: pages_for(plen, ps)])
+                with _span("serve.prefix"):
+                    self.prefix_cache.insert(
+                        st["prompt"], self._pages[s][: pages_for(plen, ps)])
             room = self.max_len - plen + 1
             if room < req.max_new_tokens:
                 logger.warning(
@@ -1637,9 +1664,9 @@ class ServingEngine:
         idx = np.zeros((self.max_blocks,), np.int32)
         idx[: len(pages)] = pages
         gathered = self._gather_pages(self.states, jnp.asarray(idx))
-        payload = [np.asarray(jax.device_get(l))
-                   for l in jax.tree.leaves(gathered)]
-        self.stats["host_syncs_admit"] += 1
+        with _span("serve.sync"):
+            payload = [np.asarray(jax.device_get(l))
+                       for l in jax.tree.leaves(gathered)]
         packet = HandoffPacket(
             request=req, prompt=st["prompt"], length=plen,
             first_token=tok, ttft_s=ttft, pages=list(pages),
@@ -1752,13 +1779,21 @@ class ServingEngine:
                  if r is not None and s not in self._admitting]
         return max(cands, key=lambda s: self._admit_seq[s]) if cands else None
 
+    def _reclaim(self, n: int) -> bool:
+        """Evict prefix-cache pages until ``n`` are allocatable; False when
+        there is no cache or it cannot free enough."""
+        if self.prefix_cache is None:
+            return False
+        with _span("serve.prefix"):
+            return self.prefix_cache.reclaim(n)
+
     def _reclaim_or_preempt(self, n: int) -> int | None:
         """Make ``n`` pages allocatable: evict prefix-cache pages first
         (cold reuse state is the cheapest thing to give back), then preempt
         the YOUNGEST decoding slot (its recompute loses the least work).
         Returns the preempted slot, or None when cache eviction sufficed;
         raises when nothing is left to take."""
-        if self.prefix_cache is not None and self.prefix_cache.reclaim(n):
+        if self._reclaim(n):
             return None
         victim = self._youngest_decoding()
         if victim is None:
@@ -1805,8 +1840,7 @@ class ServingEngine:
                             self._bt_host[slot, j] = new
                             self._bt_dirty = True
                         break
-                    if (self.prefix_cache is not None
-                            and self.prefix_cache.reclaim(1)):
+                    if self._reclaim(1):
                         continue
                     if bm.ref[pages[j]] <= 1:
                         break
@@ -1853,26 +1887,31 @@ class ServingEngine:
         position, then run ONE fused program over all B rows through the
         device block tables. Mid-prefill rows ride along on the null page
         (ctrl-inactive, zero block-table rows)."""
-        for s in range(self.slots):
-            if self.active[s] is None or s in self._admitting:
-                continue
-            length = int(self._len_host[s])
-            if length >= self.max_len:
-                continue
-            self._prepare_write(s, length, length + 1)
-        armed = [s for s, r in enumerate(self.active)
-                 if r is not None and s not in self._admitting]
-        if not armed:
-            return
-        self.rng, self.states, self.ctrl, packed = self._fused_step_paged(
-            self.params, self.rng, self.states, self.ctrl, self._bt_device())
-        self.stats["decode_steps"] += 1
-        for s in armed:
-            # pessimistic host mirror: rows that hit done mid-window stop
-            # advancing on device; the flush reconciles (the extra page a
-            # stale +1 can allocate is freed at retire)
-            self._len_host[s] = min(int(self._len_host[s]) + 1, self.max_len)
-        self._pending.append(packed)
+        with _span("serve.decode") as span:
+            for s in range(self.slots):
+                if self.active[s] is None or s in self._admitting:
+                    continue
+                length = int(self._len_host[s])
+                if length >= self.max_len:
+                    continue
+                self._prepare_write(s, length, length + 1)
+            armed = [s for s, r in enumerate(self.active)
+                     if r is not None and s not in self._admitting]
+            if not armed:
+                return
+            span.set_metadata(rows=len(armed))
+            self.rng, self.states, self.ctrl, packed = self._fused_step_paged(
+                self.params, self.rng, self.states, self.ctrl,
+                self._bt_device())
+            self.stats["decode_steps"] += 1
+            self.stats["decode_rows"] += len(armed)
+            for s in armed:
+                # pessimistic host mirror: rows that hit done mid-window stop
+                # advancing on device; the flush reconciles (the extra page a
+                # stale +1 can allocate is freed at retire)
+                self._len_host[s] = min(int(self._len_host[s]) + 1,
+                                        self.max_len)
+            self._pending.append(packed)
         if len(self._pending) >= self.sync_every or all(
             len(self.generated[i]) + len(self._pending) >= r.max_new_tokens
             for i, r in enumerate(self.active)
@@ -1911,7 +1950,7 @@ class ServingEngine:
         return out
 
     def _tok_out(self, tok: jax.Array):
-        t = jax.device_get(tok)
+        t = _fetch(tok)
         self.stats["host_syncs_decode"] += 1
         return tuple(int(x) for x in t) if t.ndim else int(t)
 
@@ -1919,7 +1958,6 @@ class ServingEngine:
         req = self.active[slot]
         assert req is not None
         decode_s = time.perf_counter() - self._admit_s[slot]
-        self.stats["decode_sum_s"] += decode_s
         self.results[req.request_id] = RequestResult(
             request_id=req.request_id,
             tokens=self.generated[slot],
@@ -1955,11 +1993,12 @@ class ServingEngine:
         """One engine iteration: admit, run one fused decode program for all
         B slots, sync the packed result (every ``sync_every`` steps), retire
         finished. Returns number of host-visible active slots."""
-        with self._bound():
+        with self._bound(), _span("serve.step"):
             return self._step_bound()
 
     def _step_bound(self) -> int:
-        self._admit()
+        with _span("serve.admit"):
+            self._admit()
         if not any(r is not None for r in self.active):
             self._flush()
             return 0
@@ -1973,13 +2012,17 @@ class ServingEngine:
                 # step
                 return sum(r is not None for r in self.active)
         if self.spec is not None:
-            self._step_spec()
+            with _span("serve.decode"):
+                self._step_spec()
         elif self.paged:
             self._step_fused_paged()
         elif self.fused:
-            self.rng, self.states, self.ctrl, packed = self._fused_step(
-                self.params, self.rng, self.states, self.ctrl)
+            rows = sum(r is not None for r in self.active)
+            with _span("serve.decode", rows=rows):
+                self.rng, self.states, self.ctrl, packed = self._fused_step(
+                    self.params, self.rng, self.states, self.ctrl)
             self.stats["decode_steps"] += 1
+            self.stats["decode_rows"] += rows
             self._pending.append(packed)
             # flush at the window boundary — or early, when every in-flight
             # request has provably hit its token budget (each active slot
@@ -1992,7 +2035,8 @@ class ServingEngine:
             ):
                 self._flush()
         else:
-            self._step_host()
+            with _span("serve.decode"):
+                self._step_host()
         return sum(r is not None for r in self.active)
 
     def _step_spec(self) -> None:
@@ -2038,7 +2082,7 @@ class ServingEngine:
                 jnp.asarray(drafts), jnp.asarray(ndraft))
         self.stats["decode_steps"] += 1
         self.stats["spec_steps"] += 1
-        arr = np.asarray(jax.device_get(packed))
+        arr = np.asarray(_fetch(packed))
         self.stats["host_syncs_decode"] += 1
         for i in range(self.slots):
             if not arr[i, c + 1]:  # slot inactive at this step
@@ -2053,7 +2097,7 @@ class ServingEngine:
                 [self._hist[i], np.asarray(toks, np.int32)])
             self._len_host[i] += m
             self._last_host[i] = toks[-1]
-            self.stats["spec_slot_steps"] += 1
+            self.stats["decode_rows"] += 1
             self.stats["spec_positions"] += c
             self.stats["spec_drafted"] += int(ndraft[i])
             self.stats["spec_accepted"] += max(m - 1, 0)
@@ -2075,7 +2119,7 @@ class ServingEngine:
             "acceptance_rate": round(a / max(d, 1), 4),
             "tokens_per_slot_step": round(
                 self.stats["spec_emitted"]
-                / max(self.stats["spec_slot_steps"], 1), 4),
+                / max(self.stats["decode_rows"], 1), 4),
         }
 
     def latency_summary(self) -> dict:
@@ -2097,23 +2141,24 @@ class ServingEngine:
         and replay them through the host-side slot table."""
         if not self._pending:
             return
-        rows = jax.device_get(self._pending)
-        self._pending = []
-        self.stats["host_syncs_decode"] += 1
-        audio = self.cfg.frontend == "audio"
-        for arr in rows:  # (B, T+2): tokens..., active, done
-            arr = np.asarray(arr)
-            for i in range(self.slots):
-                if not arr[i, -2]:  # slot inactive at that step
-                    continue
-                req = self.active[i]
-                if req is None:
-                    continue
-                tok = arr[i, :-2]
-                self.generated[i].append(
-                    tuple(int(x) for x in tok) if audio else int(tok[0]))
-                if arr[i, -1]:
-                    self._retire(i)
+        with _span("serve.flush", steps=len(self._pending)):
+            rows = _fetch(self._pending)
+            self._pending = []
+            self.stats["host_syncs_decode"] += 1
+            audio = self.cfg.frontend == "audio"
+            for arr in rows:  # (B, T+2): tokens..., active, done
+                arr = np.asarray(arr)
+                for i in range(self.slots):
+                    if not arr[i, -2]:  # slot inactive at that step
+                        continue
+                    req = self.active[i]
+                    if req is None:
+                        continue
+                    tok = arr[i, :-2]
+                    self.generated[i].append(
+                        tuple(int(x) for x in tok) if audio else int(tok[0]))
+                    if arr[i, -1]:
+                        self._retire(i)
 
     def _step_host(self) -> None:
         """Legacy per-slot host loop (the seed data plane): B scalar sample
@@ -2123,6 +2168,7 @@ class ServingEngine:
         logits, self.states = self._decode(
             self.params, self.ctrl["last"], self.states, self.ctrl["lengths"])
         self.stats["decode_steps"] += 1
+        self.stats["decode_rows"] += sum(r is not None for r in self.active)
         new_tokens = []
         for i in range(self.slots):
             req = self.active[i]

@@ -320,7 +320,6 @@ def test_request_latency_telemetry():
     assert lat["requests"] == 4
     assert lat["ttft_p95_s"] >= lat["ttft_p50_s"] > 0
     assert lat["tpot_p95_s"] >= lat["tpot_p50_s"] > 0
-    assert eng.stats["ttft_sum_s"] > 0
 
 
 def test_fleet_report_spec_and_latency():

@@ -299,7 +299,11 @@ def param_shardings(params, mesh) -> object:
 # recipes can replicate small per-token activations (2D weight-stationary TP
 # at decode) without replicating the KV cache.
 STATE_RULES: list[tuple[str, tuple[str | None, ...]]] = [
+    # slot cache (B, max_len, Hkv, D)
     (r"(^|/)(k|v)$", ("state_batch", "kv_seq", "kv_heads", None)),
+    # paged pool (pages, page, Hkv*D): each head's D lanes are contiguous, so
+    # a model axis that divides Hkv splits the minor axis at head bounds
+    (r"(^|/)(k|v)$", ("state_batch", "kv_seq", "kv_heads")),
     (r"ckv$", ("state_batch", "kv_seq", None)),
     (r"krope$", ("state_batch", "kv_seq", None)),
     (r"conv$", ("state_batch", None, "lru")),
@@ -312,6 +316,20 @@ STATE_RULES: list[tuple[str, tuple[str | None, ...]]] = [
 _STATE_COMPILED = [(re.compile(pat), spec) for pat, spec in STATE_RULES]
 
 
+_SCANNED = re.compile(r"(^|/)scan/")
+
+
+def _state_rule(s: str, leaf):
+    """The rule of the leaf at path ``s``: among the rules its path matches,
+    the one as long as the leaf's rank without the stack axis of a scanned
+    (``scan/...``) leaf, else the first. A paged pool and a slot cache
+    share their paths and differ in rank."""
+    matches = [spec for pat, spec in _STATE_COMPILED if pat.search(s)]
+    rank = leaf.ndim - bool(_SCANNED.search(s))
+    return next((m for m in matches if len(m) == rank),
+                matches[0] if matches else None)
+
+
 def state_pspecs(states) -> object:
     """Pytree of PartitionSpec for a serving-state tree. Stacked (scanned)
     states get a leading replicated 'stack' dim by ndim mismatch, same as
@@ -319,18 +337,14 @@ def state_pspecs(states) -> object:
     truncation."""
 
     def annotate(path, leaf):
-        s = _path_str(path)
-        for pat, spec in _STATE_COMPILED:
-            if pat.search(s):
-                if len(spec) < leaf.ndim:
-                    spec2 = ("stack",) * (leaf.ndim - len(spec)) + tuple(spec)
-                elif len(spec) > leaf.ndim:
-                    spec2 = ("state_batch",) + tuple(
-                        spec[len(spec) - leaf.ndim + 1:])
-                else:
-                    spec2 = tuple(spec)
-                return guarded_spec(leaf.shape, spec2)
-        return guarded_spec(leaf.shape, ("state_batch",))
+        spec = _state_rule(_path_str(path), leaf)
+        if spec is None:
+            return guarded_spec(leaf.shape, ("state_batch",))
+        if len(spec) < leaf.ndim:
+            spec = ("stack",) * (leaf.ndim - len(spec)) + tuple(spec)
+        elif len(spec) > leaf.ndim:
+            spec = ("state_batch",) + tuple(spec[len(spec) - leaf.ndim + 1:])
+        return guarded_spec(leaf.shape, tuple(spec))
 
     return jax.tree_util.tree_map_with_path(annotate, states)
 

@@ -328,7 +328,7 @@ def _probe_decode(interpret):
 def _probe_paged_decode(interpret):
     def probe(profile):
         q = jnp.zeros((2, 8, 128), jnp.float32)
-        pool = jnp.zeros((5, 8, 2, 128), jnp.float32)
+        pool = jnp.zeros((5, 8, 2 * 128), jnp.float32)  # (P, page, Hkv*D)
         bt = jnp.array([[1, 2], [3, 4]], jnp.int32)
         lengths = jnp.array([5, 16], jnp.int32)
         _paged_pallas.paged_decode_attention(

@@ -3,7 +3,7 @@
 vLLM-style PagedAttention adapted to the flash-decoding kernel in
 ``decode_attention.py``: the KV cache is no longer one contiguous
 ``(B, S, Hkv, D)`` region per batch row but a shared physical pool of
-fixed-size pages ``(P, page, Hkv, D)``, and each row owns an int32 *block
+fixed-size pages ``(P, page, Hkv*D)``, and each row owns an int32 *block
 table* mapping its logical page j to a physical page id. The kernel walks a
 row's logical pages along a sequential grid axis; the page indirection
 happens in the BlockSpec index map, which reads the scalar-prefetched block
@@ -12,10 +12,14 @@ in logical order, and the online-softmax recurrence is unchanged from the
 contiguous kernel.
 
 Grid: (B, N) with N = pool pages per row (block-table width); logical page
-j covers absolute positions [j*page, (j+1)*page). One grid step streams a
-whole ``(page, Hkv, D)`` page: its last two dims are the pool's own, which
-is what Mosaic requires of a block whose Hkv is below the sublane tile, and
-each KV head's (page, D) slice is read out of it in VMEM. Pages entirely
+j covers absolute positions [j*page, (j+1)*page). The pool is lane-dense:
+KV head h of a position sits at lanes [h*D, (h+1)*D) of the minor axis, so
+one grid step streams a whole ``(1, page, Hkv*D)`` page (for qwen2-0.5b,
+Hkv*D = 128 and a bf16 page is one unpadded (16, 128) tile) and each KV
+head's (page, D) lane slice is read out of it in VMEM. With (Hkv, D) as the
+two minor dims instead, a page of D = 64 fills half of every padded tile;
+XLA then lays the pool out another way than the kernel's operand, and
+re-lays a whole layer's pool around every call. Pages entirely
 past a row's ``length`` are skipped block-level (``pl.when`` — no compute
 for the unallocated suffix, whose table entries point at the reserved null
 page 0). Window (local attention) masks positions < length - window.
@@ -38,12 +42,12 @@ def _paged_decode_kernel(
     len_ref,  # SMEM (B,)    valid lengths, scalar-prefetched
     bt_ref,  # SMEM (B, N)   block tables, scalar-prefetched
     q_ref,  # (1, Hkv, G, D)
-    k_ref,  # (1, page, Hkv, D)  the physical page the index map gathered
+    k_ref,  # (1, page, Hkv*D)  the physical page the index map gathered
     v_ref,
     o_ref,  # (1, Hkv, G, D)
     m_scr, l_scr, acc_scr,  # (Hkv, G), (Hkv, G), (Hkv, G, D)
     *, scale: float, window: int | None, softcap: float | None, page: int,
-    hkv: int,
+    hkv: int, d: int,
 ):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -62,9 +66,10 @@ def _paged_decode_kernel(
     @pl.when(run)
     def _compute():
         for h in range(hkv):
+            lanes = slice(h * d, (h + 1) * d)  # KV head h's lanes
             q = q_ref[0, h].astype(jnp.float32)  # (G, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = k_ref[0, :, lanes].astype(jnp.float32)  # (page, D)
+            v = v_ref[0, :, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (G, page)
@@ -98,7 +103,7 @@ def _paged_decode_kernel(
     static_argnames=("window", "scale", "logit_softcap", "interpret"))
 def paged_decode_attention(
     q: jax.Array,  # (B, Hq, D)
-    k_pool: jax.Array,  # (P, page, Hkv, D) shared physical page pool
+    k_pool: jax.Array,  # (P, page, Hkv*D) shared physical page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, N) int32 physical page ids
     *,
@@ -110,9 +115,10 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Drop-in for the `paged_decode_attention` hook ABI (kernels/ref.py)."""
     b, hq, d = q.shape
-    page, hkv = k_pool.shape[1], k_pool.shape[2]
+    page = k_pool.shape[1]
+    hkv = k_pool.shape[2] // d
     n = block_tables.shape[1]
-    assert hq % hkv == 0
+    assert k_pool.shape[2] == hkv * d and hq % hkv == 0
     g = hq // hkv
     scale = scale if scale is not None else d**-0.5
     if lengths is None:
@@ -121,7 +127,7 @@ def paged_decode_attention(
     qt = q.reshape(b, hkv, g, d)
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window,
-        softcap=logit_softcap, page=page, hkv=hkv)
+        softcap=logit_softcap, page=page, hkv=hkv, d=d)
 
     out = pl.pallas_call(
         kernel,
@@ -134,11 +140,11 @@ def paged_decode_attention(
                 # the paging indirection: logical page j of row b_ is the
                 # physical pool page the prefetched table names
                 pl.BlockSpec(
-                    (1, page, hkv, d),
-                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0, 0)),
+                    (1, page, hkv * d),
+                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0)),
                 pl.BlockSpec(
-                    (1, page, hkv, d),
-                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0, 0)),
+                    (1, page, hkv * d),
+                    lambda b_, j, lens, bt: (bt[b_, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, hkv, g, d), lambda b_, j, lens, bt: (b_, 0, 0, 0)),

@@ -190,6 +190,15 @@ def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
+def _gather_heads(pool: jax.Array, block_tables: jax.Array,
+                  d: int) -> jax.Array:
+    """A lane-dense (P, page, Hkv*D) pool's rows as (B, N*page, Hkv, D)
+    caches. Only the gathered rows are split into heads, never the whole
+    pool, so no program re-lays a pool to read it."""
+    g = gather_pages(pool, block_tables)
+    return g.reshape(g.shape[:2] + (g.shape[2] // d, d))
+
+
 def paged_decode_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -203,13 +212,17 @@ def paged_decode_attention(
 ) -> jax.Array:
     """One-token decode attention over a paged KV pool.
 
-    q: (B, Hq, Dh); k_pool, v_pool: (P, page, Hkv, Dh); block_tables: (B, N)
-    int32. The portable tier gathers the pool into the contiguous layout and
-    runs the contiguous oracle, so it is byte-identical to the slot engine's
-    reference path — the parity anchor every paged tier is validated against.
+    q: (B, Hq, Dh); k_pool, v_pool: (P, page, Hkv*Dh), lane-dense: KV head
+    h of a position at lanes [h*Dh, (h+1)*Dh), so a page of Hkv*Dh = 128
+    bf16 lanes is one unpadded (16, 128) tile; block_tables: (B, N) int32.
+    The portable tier gathers each row's pages, splits heads on the gathered
+    cache and runs the contiguous oracle, so it is byte-identical to the
+    slot engine's reference path — the parity anchor every paged tier is
+    validated against.
     """
-    k_cache = gather_pages(k_pool, block_tables)
-    v_cache = gather_pages(v_pool, block_tables)
+    d = q.shape[-1]
+    k_cache = _gather_heads(k_pool, block_tables, d)
+    v_cache = _gather_heads(v_pool, block_tables, d)
     return decode_attention(
         q, k_cache, v_cache, lengths=lengths, window=window, scale=scale,
         logit_softcap=logit_softcap)
@@ -228,9 +241,11 @@ def paged_chunk_attention(
 ) -> jax.Array:
     """Chunked-prefill attention over a paged KV pool: q (B, Sq, Hq, Dh) at
     absolute ``positions`` (B, Sq) attends the gathered logical caches (the
-    chunk's own entries already scattered into the pool)."""
-    k_cache = gather_pages(k_pool, block_tables)
-    v_cache = gather_pages(v_pool, block_tables)
+    chunk's own entries already scattered into the pool). Pools are
+    (P, page, Hkv*Dh), laid out as for :func:`paged_decode_attention`."""
+    d = q.shape[-1]
+    k_cache = _gather_heads(k_pool, block_tables, d)
+    v_cache = _gather_heads(v_pool, block_tables, d)
     return chunk_attention(
         q, k_cache, v_cache, positions=positions, window=window, scale=scale,
         logit_softcap=logit_softcap)
@@ -353,14 +368,14 @@ def _register() -> None:
     )
     hooks.register_api(
         "paged_decode_attention",
-        "paged_decode_attention(q(B,Hq,D), k_pool(P,page,Hkv,D), v_pool,"
+        "paged_decode_attention(q(B,Hq,D), k_pool(P,page,Hkv*D), v_pool,"
         " block_tables(B,N), *, lengths(B,), window, scale, logit_softcap)"
         " -> (B,Hq,D)",
         paged_decode_attention,
     )
     hooks.register_api(
         "paged_chunk_attention",
-        "paged_chunk_attention(q(B,Sq,Hq,D), k_pool(P,page,Hkv,D), v_pool,"
+        "paged_chunk_attention(q(B,Sq,Hq,D), k_pool(P,page,Hkv*D), v_pool,"
         " block_tables(B,N), *, positions(B,Sq), window, scale,"
         " logit_softcap) -> (B,Sq,Hq,D)",
         paged_chunk_attention,

@@ -155,10 +155,12 @@ def prefill_chunk(p, cfg, x, positions, state, start, lengths, *,
 def init_paged_state(cfg, num_pages: int, page_size: int, dtype):
     """Paged KV pool: ``num_pages`` fixed-size pages shared by every request
     (physical page 0 is the engine's reserved null page). Same tree structure
-    as :func:`init_state` with the (batch, max_len) axes replaced by
-    (num_pages, page_size)."""
+    as :func:`init_state`, with pools of (num_pages, page_size, Hkv*Dh): KV
+    head h of a position at lanes [h*Dh, (h+1)*Dh). Lane-dense, a page is
+    whole tiles, so XLA keeps the pool in the layout the paged kernel reads
+    (kernels/paged_attention.py) and never re-lays it around a call."""
     hd = cfg.resolved_head_dim
-    shape = (num_pages, page_size, cfg.num_kv_heads, hd)
+    shape = (num_pages, page_size, cfg.num_kv_heads * hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -170,7 +172,7 @@ def prefill_chunk_paged(p, cfg, x, positions, state, block_tables, page_size,
     reserved null page 0 instead of dropped — same stale-beyond-the-length
     contract, no owned page is ever touched by a pad write.
 
-    state: {"k","v"} (P, page, Hkv, Dh) pools; block_tables: (B, N) int32.
+    state: {"k","v"} (P, page, Hkv*Dh) pools; block_tables: (B, N) int32.
     """
     b, s, _ = x.shape
     q, k, v = _qkv(p, cfg, x, positions)
@@ -184,8 +186,10 @@ def prefill_chunk_paged(p, cfg, x, positions, state, block_tables, page_size,
     phys = jnp.take_along_axis(block_tables, page_idx, axis=1)
     phys = jnp.where(valid, phys, 0)
     offset = positions % page_size
-    k_pool = state["k"].at[phys, offset].set(k.astype(state["k"].dtype))
-    v_pool = state["v"].at[phys, offset].set(v.astype(state["v"].dtype))
+    k_pool = state["k"].at[phys, offset].set(
+        k.reshape(b, s, -1).astype(state["k"].dtype))
+    v_pool = state["v"].at[phys, offset].set(
+        v.reshape(b, s, -1).astype(state["v"].dtype))
     o = hooks.call(
         "paged_chunk_attention", q, k_pool, v_pool, block_tables,
         positions=positions, window=window, logit_softcap=cfg.logit_softcap,
@@ -216,8 +220,10 @@ def decode_paged(p, cfg, x, state, block_tables, page_size, lengths, *,
     phys = jnp.take_along_axis(block_tables, page_idx[:, None], axis=1)[:, 0]
     phys = jnp.where(lengths > 0, phys, 0)
     offset = safe % page_size
-    k_pool = state["k"].at[phys, offset].set(k[:, 0].astype(state["k"].dtype))
-    v_pool = state["v"].at[phys, offset].set(v[:, 0].astype(state["v"].dtype))
+    k_pool = state["k"].at[phys, offset].set(
+        k.reshape(b, -1).astype(state["k"].dtype))
+    v_pool = state["v"].at[phys, offset].set(
+        v.reshape(b, -1).astype(state["v"].dtype))
     o = hooks.call(
         "paged_decode_attention", q[:, 0], k_pool, v_pool, block_tables,
         lengths=lengths, window=window, logit_softcap=cfg.logit_softcap,

@@ -13,7 +13,9 @@ The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and pytest workers import every
 test file.
 """
+import dataclasses
 import pathlib
+import re
 import types
 
 import jax
@@ -70,8 +72,8 @@ CASES = {
     "paged_decode_attention": (
         lambda q, kp, vp, bt, lens: pg.paged_decode_attention(
             q, kp, vp, bt, lengths=lens),
-        [((8, HQ, D), BF16), ((513, 16, HKV, D), BF16),
-         ((513, 16, HKV, D), BF16), ((8, 64), I32), ((8,), I32)]),
+        [((8, HQ, D), BF16), ((513, 16, HKV * D), BF16),
+         ((513, 16, HKV * D), BF16), ((8, 64), I32), ((8,), I32)]),
     "rmsnorm": (
         lambda x, w: rms.rmsnorm(x, w),
         [((8, DM), BF16), ((DM,), BF16)]),
@@ -89,6 +91,64 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the paged serving programs keep the KV pool in the kernel's layout
+# ---------------------------------------------------------------------------
+# 2 layers of qwen2-0.5b, 513 pages of 16, 8 rows of 64 blocks, 64-wide chunk
+PAGES, PAGE, ROWS, BLOCKS, CHUNK = 513, 16, 8, 64, 64
+_COPY = re.compile(r"= \w+\[([\d,]*)\]\S* copy\(")
+
+
+def _paged_program(which, one_chip):
+    from repro import configs
+    from repro.core import hooks
+    from repro.kernels import ops  # noqa: F401  (registers the tiers)
+    from repro.models import transformer
+
+    cfg = dataclasses.replace(configs.get_config("qwen2-0.5b"), num_layers=2)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init_model(jax.random.key(0), cfg)))
+    states = on_chip(jax.eval_shape(
+        lambda: transformer.init_paged_states(cfg, PAGES, PAGE, BF16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=one_chip)  # noqa
+    bt = i32(ROWS, BLOCKS)
+    if which == "decode":
+        def fn(p, t, s, lens, bt, key):
+            return transformer.decode_and_sample(
+                p, cfg, t, s, lens, key,
+                lambda k, lg: jnp.argmax(lg, -1).astype(I32),
+                block_tables=bt, page_size=PAGE)
+        key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+        args = (params, i32(ROWS), states, i32(ROWS), bt, key)
+    else:
+        def fn(p, t, s, start, lens, bt):
+            return transformer.prefill_chunk(
+                p, cfg, t, s, start, lens, block_tables=bt, page_size=PAGE)
+        args = (params, i32(ROWS, CHUNK), states, i32(ROWS), i32(ROWS), bt)
+    binding = hooks.bind(recompile.TPU_V5E)
+    assert binding.choices["paged_decode_attention"].provider == "pallas-tpu"
+    with hooks.use(binding):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_paged_programs_never_relay_the_kv_pool(which, one_chip):
+    """No ``copy`` in the optimized program has a shape carrying the pool's
+    page count: a layer's pool is never re-laid between the layout XLA
+    keeps it in and the one the paged kernel reads."""
+    hlo = _paged_program(which, one_chip)
+    if which == "decode":
+        assert "tpu_custom_call" in hlo
+    relays = [m.group(0) for m in _COPY.finditer(hlo)
+              if str(PAGES) in m.group(1).split(",")]
+    assert relays == []
 
 
 # ---------------------------------------------------------------------------

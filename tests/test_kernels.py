@@ -101,7 +101,7 @@ def test_paged_decode_attention_vs_ref(hq, hkv, d, page, window, softcap,
     n, b = 4, 3
     ks = jax.random.split(jax.random.fold_in(KEY, hq * 7 + page), 3)
     q = jax.random.normal(ks[0], (b, hq, d), jnp.float32).astype(dtype)
-    shape = (1 + b * n, page, hkv, d)
+    shape = (1 + b * n, page, hkv * d)  # head h at lanes [h*d, (h+1)*d)
     kp = jax.random.normal(ks[1], shape, jnp.float32).astype(dtype)
     vp = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
     # rows own disjoint, shuffled pages; page 0 is the null page
@@ -114,6 +114,48 @@ def test_paged_decode_attention_vs_ref(hq, hkv, d, page, window, softcap,
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=TOL[dtype], rtol=TOL[dtype])
+
+
+def _lane_dense_pool(cache, bt, page):
+    """Lay contiguous (B, N*page, Hkv, D) caches into a (1 + B*N, page,
+    Hkv*D) pool through block tables ``bt``, one head's lanes at a time:
+    KV head h of position t of row b lands at lanes [h*D, (h+1)*D) of page
+    bt[b, t // page], offset t % page. Page 0, the null page, holds noise."""
+    cache = np.asarray(cache)
+    b, _, hkv, d = cache.shape
+    pool = np.random.default_rng(5).standard_normal(
+        (1 + bt.size, page, hkv * d)).astype(cache.dtype)
+    for r in range(b):
+        for t in range(cache.shape[1]):
+            for h in range(hkv):
+                pool[bt[r, t // page], t % page, h * d:(h + 1) * d] = \
+                    cache[r, t, h]
+    return jnp.asarray(pool)
+
+
+@pytest.mark.parametrize("api", ["paged_chunk_attention",
+                                 "paged_decode_attention"])
+def test_paged_attention_on_lane_dense_pool_vs_contiguous_oracle(api):
+    """The portable paged tiers read a lane-dense pool exactly as the
+    contiguous oracle reads the caches the pool was laid from."""
+    b, n, page, hq, hkv, d, sq = 2, 3, 8, 4, 2, 32, 5
+    _, k, v = _qkv(b, 1, n * page, hq, hkv, d, jnp.float32)
+    q = jax.random.normal(jax.random.fold_in(KEY, 3), (b, sq, hq, d))
+    perm = np.random.default_rng(7).permutation(b * n) + 1
+    bt = perm.reshape(b, n).astype(np.int32)
+    kp, vp = _lane_dense_pool(k, bt, page), _lane_dense_pool(v, bt, page)
+    lengths = jnp.asarray([page + 3, n * page], jnp.int32)
+    if api == "paged_chunk_attention":
+        pos = lengths[:, None] - sq + jnp.arange(sq)[None, :]
+        want = ref.chunk_attention(q, k, v, positions=pos)
+        got = ref.paged_chunk_attention(q, kp, vp, jnp.asarray(bt),
+                                        positions=pos)
+    else:
+        want = ref.decode_attention(q[:, 0], k, v, lengths=lengths)
+        got = ref.paged_decode_attention(q[:, 0], kp, vp, jnp.asarray(bt),
+                                         lengths=lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=TOL[jnp.float32], rtol=TOL[jnp.float32])
 
 
 # ---------------------------------------------------------------------------

@@ -104,6 +104,38 @@ def test_state_rules_cover_all_archs():
             shd.state_pspecs(states)  # must not raise
 
 
+_KV_RULES = dict(shd.RULES_2D, kv_seq="pod")  # every KV axis its own mesh axis
+_KV_MESH = _mesh((2, 2, 2), ("pod", "data", "model"))
+
+
+@pytest.mark.parametrize("layout,stacked,want", [
+    # paged pool (pages, page, Hkv*D): pages -> state_batch, page slots ->
+    # kv_seq, the lane-dense heads -> kv_heads
+    ("paged", True, P(None, "data", "pod", "model")),
+    ("paged", False, P("data", "pod", "model")),
+    # slot cache (B, max_len, Hkv, D) keeps its own rule
+    ("slot", True, P(None, "data", "pod", "model", None)),
+    ("slot", False, P("data", "pod", "model", None)),
+])
+def test_state_pspecs_kv_pool_and_slot_cache(layout, stacked, want):
+    cfg = configs.get_config("qwen2-0.5b-smoke")
+    if layout == "paged":
+        states = jax.eval_shape(
+            lambda: transformer.init_paged_states(cfg, 8, 16, jnp.float32))
+    else:
+        states = jax.eval_shape(
+            lambda: transformer.init_states(cfg, 2, 16, jnp.float32))
+    if not stacked:  # one layer's state, as an unscanned prefix layer holds
+        one = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+            states["scan"][0])
+        states = {"prefix": (one,), "scan": ()}
+    with shd.use_rules(_KV_RULES, _KV_MESH):
+        specs = shd.state_pspecs(states)
+    layer = specs["scan"][0] if stacked else specs["prefix"][0]
+    assert layer["k"] == want and layer["v"] == want
+
+
 # ---------------------------------------------------------------------------
 # Sharded serving data plane: stream parity on a real multi-device mesh.
 # ---------------------------------------------------------------------------

@@ -43,13 +43,14 @@ def main(argv=None) -> int:
     bench = harness.Bench(ROOT)
     cell = bench.cell(args.workload)
     conf, mix = bench.config(cell), bench.mix(cell)
+    family = bench.family(conf)
     limits = bench.limits(cell)
-    pad = check.pad_length(conf["engine"]["max_len"])
+    pad = check.pad_length(conf["engine"]["max_len"], family.Q_BLOCK)
     rows = []
     for seed in (int(s) for s in args.seeds.split(",")):
         traffic = bench.generator(mix).make(mix, seed, args.seconds,
                                             conf["model"]["vocab_size"])
-        serving = run.Setup(cell["config"], conf, seed, CLOCK)
+        serving = run.Setup(family, cell["config"], conf, seed, CLOCK)
         client, _ = run._serve(serving, traffic, args.seconds, None,
                             run.CompileCounter())
         recs, _ = client.relative()
@@ -60,9 +61,9 @@ def main(argv=None) -> int:
         gc.collect()
         sample = check.sample(cases, seed, limits["sample_tokens"])
         t = CLOCK()
-        prog = check.served_gaps(conf["model"], seed, sample, pad)
+        prog = check.served_gaps(family, conf, seed, sample, pad)
         t_ref = CLOCK() - t
-        ctrl = check.control_gaps(conf["model"], seed, sample, pad)
+        ctrl = check.control_gaps(family, conf, seed, sample, pad)
         row = {"seed": seed, "requests": len(sample), "tokens": len(prog),
                "short": short, "program_gap": float(prog.max()),
                "program_gap_p99": float(sorted(prog)[int(0.99 * len(prog))]),

@@ -7,17 +7,17 @@ with its served tokens. For every served token the reference's best logit
 at that position, less its logit of the served token, is the token's gap;
 the widest gap is compared with the cell's limit. That holds for greedy
 tokens, which the mixes serve. The control is the same reference in
-float8: at the same positions, the gap of the token it puts first.
+float8: at the same positions, the gap of the token it puts first. The
+reference is the configuration's family's (``bench/families/``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench.reference.qwen2 import Q_BLOCK, Reference
 
-
-def pad_length(max_len: int) -> int:
-    return -(-max_len // Q_BLOCK) * Q_BLOCK
+def pad_length(max_len: int, q_block: int) -> int:
+    """``max_len`` rounded up to the reference's ``q_block``."""
+    return -(-max_len // q_block) * q_block
 
 
 def sample(cases: list[tuple[int, np.ndarray, list[int]]], seed: int,
@@ -47,23 +47,25 @@ def _inputs(cases):
     return seqs, want
 
 
-def served_gaps(model: dict, seed: int, cases, pad_to: int) -> np.ndarray:
+def served_gaps(family, conf: dict, seed: int, cases,
+                pad_to: int) -> np.ndarray:
     """Per served token: reference best logit less the served token's."""
     seqs, want = _inputs(cases)
     out = []
-    for lg, (_, _, t) in zip(Reference(model, seed).logits(seqs, want,
-                                                          pad_to), cases):
+    for lg, (_, _, t) in zip(family.Reference(conf, seed).logits(
+            seqs, want, pad_to), cases):
         lg = np.asarray(lg)
         out.append(lg.max(-1) - lg[np.arange(len(t)), np.asarray(t)])
     return np.concatenate(out) if out else np.zeros((0,))
 
 
-def control_gaps(model: dict, seed: int, cases, pad_to: int) -> np.ndarray:
+def control_gaps(family, conf: dict, seed: int, cases,
+                 pad_to: int) -> np.ndarray:
     """Per position: reference best logit less its logit of the token the
     float8 reference puts first."""
     seqs, want = _inputs(cases)
-    ref = Reference(model, seed).logits(seqs, want, pad_to)
-    low = Reference(model, seed, fp8=True).logits(seqs, want, pad_to)
+    ref = family.Reference(conf, seed).logits(seqs, want, pad_to)
+    low = family.Reference(conf, seed, fp8=True).logits(seqs, want, pad_to)
     out = []
     for r, c in zip(ref, low):
         r, top = np.asarray(r), np.asarray(c).argmax(-1)

@@ -38,7 +38,7 @@ def fit(bench, name: str, one_chip, pins=None) -> None:
     import jax
     import jax.numpy as jnp
 
-    from bench import model as bm, weights
+    from bench import weights
     from repro.core import hooks, recompile
     from repro.kernels import ops  # noqa: F401  (registers the tiers)
     from repro.models import transformer
@@ -46,8 +46,9 @@ def fit(bench, name: str, one_chip, pins=None) -> None:
 
     cell = bench.cell(name)
     conf = bench.config(cell)
-    model, eng = conf["model"], conf["engine"]
-    cfg = bm.arch_config(cell["config"], model)
+    family = bench.family(conf)
+    eng = conf["engine"]
+    cfg = family.arch_config(cell["config"], conf)
     dt = jnp.dtype(cfg.param_dtype)
     slots, cap, ps = eng["slots"], eng["prefill_chunk_tokens"], \
         eng["page_size"]
@@ -60,7 +61,7 @@ def fit(bench, name: str, one_chip, pins=None) -> None:
 
     key = on_chip(jax.eval_shape(lambda: weights.seed_key(0)))
     params = on_chip(jax.eval_shape(
-        lambda k: bm._program_tree(k, model, dt), weights.seed_key(0)))
+        lambda k: family.program_tree(k, conf, dt), weights.seed_key(0)))
     states = on_chip(jax.eval_shape(
         lambda: transformer.init_paged_states(cfg, eng["kv_pages"], ps, dt)))
     i32 = lambda *s: on_chip(jax.ShapeDtypeStruct(s, jnp.int32))  # noqa
@@ -68,7 +69,7 @@ def fit(bench, name: str, one_chip, pins=None) -> None:
     print(f"{name}: tiers " + " ".join(
         f"{a}={c.provider}" for a, c in sorted(binding.choices.items())))
 
-    make = jax.jit(lambda k: bm._program_tree(k, model, dt))
+    make = jax.jit(lambda k: family.program_tree(k, conf, dt))
     _report("weights", make.lower(key).compile())
 
     def prefill(p, t, s, start, lens, bt):

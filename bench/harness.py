@@ -1,10 +1,15 @@
-"""Finds what a cell names, by name: its configuration, its traffic mix
-and generator, its metrics' readers and its correctness limits.
+"""Finds what a cell names, by name: its configuration and the
+configuration's model family, its traffic mix and generator, its metrics'
+readers and its correctness limits.
 
-A later change adds a configuration, a mix, a generator or a metric by
-adding a file and an entry in ``BENCHMARK.json``; nothing here changes.
+A later change adds a configuration, a family, a mix, a generator or a
+metric by adding a file and an entry in ``BENCHMARK.json``; nothing here
+changes.
 
 * configuration: ``bench/configs/<config>.json`` (the ``file`` of its entry)
+* model family:  ``bench/families/<model_type>.py``, by the configuration's
+                 published ``model["model_type"]`` (``bench/families/``
+                 lists what a family gives)
 * traffic mix:   ``bench/traffic/<traffic>.json``, generator
                  ``bench/traffic/<kind>.py`` with ``make(mix, seed, seconds,
                  vocab)``
@@ -35,6 +40,10 @@ class Bench:
     def config(self, cell: dict) -> dict:
         entry = self._named("configs", cell["config"])
         return json.loads((self.root / entry["file"]).read_text())
+
+    def family(self, conf: dict):
+        """The module of a configuration's model family."""
+        return self._module("families", conf["model"]["model_type"])
 
     def mix(self, cell: dict) -> dict:
         return self._json("traffic", cell["traffic"])

@@ -1,7 +1,6 @@
 """Model FLOPs of the tokens the decode steps made visible in the window
 (weights, attention at live lengths, LM head) over the fused decode
 program's device time times the peak."""
-from bench import flops
 from bench.metrics import common
 
 
@@ -9,5 +8,5 @@ def read(ctx):
     if ctx.trace is None:
         return None
     secs, _ = common.module_time(ctx, common.FUSED_DECODE)
-    f = flops.decode_flops(ctx.model, common.decode_keys(ctx))
+    f = ctx.family.decode_flops(ctx.conf, common.decode_keys(ctx))
     return common.mfu(ctx, f, secs)

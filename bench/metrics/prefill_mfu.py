@@ -1,7 +1,6 @@
 """Model FLOPs of the real prompt tokens prefilled in the window (causal
 attention, one LM head row per chunk and request) over the prefill-chunk
 programs' device time times the peak. Padding lowers it."""
-from bench import flops
 from bench.metrics import common
 
 
@@ -10,5 +9,5 @@ def read(ctx):
         return None
     secs, _ = common.module_time(ctx, common.PREFILL_CHUNK)
     spans = [(a, b) for t, _, a, b in ctx.prefill if 0.0 <= t <= ctx.close]
-    f = flops.prefill_flops(ctx.model, spans)
+    f = ctx.family.prefill_flops(ctx.conf, spans)
     return common.mfu(ctx, f, secs)

@@ -6,7 +6,8 @@ grouped-query attention with biased Q/K/V projections and rotary positions
 the LM head, tied to the embedding where ``tie_word_embeddings`` is set.
 Departures, each forced by the benchmark and none by the model:
 
-* weights are random, from ``bench.weights``; the served values (bfloat16)
+* weights are random, from the family's layout
+  (``bench/families/qwen2.py``); the served values (bfloat16)
   are widened to float32 here, so the reference and the program hold the
   same numbers;
 * RMSNorm gains are stored as ``1 + delta`` (the benchmark makes ``delta``);
@@ -101,22 +102,25 @@ def _head(x, gain, head, *, eps, fp8):
 
 
 class Reference:
-    """The reference model of one config and seed."""
+    """The reference model of one configuration file and seed."""
 
-    def __init__(self, model: dict, seed: int, *, fp8: bool = False):
-        self.model = model
+    def __init__(self, conf: dict, seed: int, *, fp8: bool = False):
+        # the family module imports this one
+        from bench.families import qwen2 as layout
+
+        model = conf["model"]
         self.dtype = jnp.dtype(model.get("torch_dtype", "bfloat16"))
         self.key = weights.seed_key(seed)
         self.fp8 = fp8
-        d = weights.dims(model)
+        self.dims = d = layout.dims(model)
         self.m = {"hq": d["hq"], "hkv": d["hkv"], "hd": d["hd"],
                   "eps": float(model["rms_norm_eps"]),
                   "theta": float(model["rope_theta"])}
         self._layer_w = jax.jit(
-            lambda key, i: weights.layer_tensors(key, model, i, self.dtype))
+            lambda key, i: layout.layer_tensors(key, model, i, self.dtype))
         self._top = jax.jit(
-            lambda key, name: weights.top_tensor(key, model, name,
-                                                 self.dtype),
+            lambda key, name: layout.top_tensor(key, model, name,
+                                                self.dtype),
             static_argnums=1)
 
     def logits(self, seqs: list[np.ndarray], want: list[np.ndarray],
@@ -135,10 +139,10 @@ class Reference:
             toks = np.zeros((pad_to,), np.int32)
             toks[: len(s)] = s
             xs.append(embed[jnp.asarray(toks)].astype(jnp.float32))
-        tied = weights.dims(self.model)["tied"]
+        tied = self.dims["tied"]
         if not tied:
             del embed
-        for i in range(weights.dims(self.model)["layers"]):
+        for i in range(self.dims["layers"]):
             w = self._layer_w(self.key, i)
             xs = [_layer(x, w, m=mkey, fp8=self.fp8) for x in xs]
             del w

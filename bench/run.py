@@ -66,9 +66,10 @@ class CompileCounter:
 
 
 class Setup:
-    """A leased, deployed and warmed serving engine for one configuration."""
+    """A leased, deployed and warmed serving engine for one configuration
+    of ``family`` (``bench/families/<model_type>.py``)."""
 
-    def __init__(self, name: str, conf: dict, seed: int, clock):
+    def __init__(self, family, name: str, conf: dict, seed: int, clock):
         import jax
 
         from bench import model as bm
@@ -76,9 +77,9 @@ class Setup:
         from repro.core.invocation import InvocationService
         from repro.serving.service import serving_container
 
-        self.cfg = bm.arch_config(name, conf["model"])
+        self.cfg = family.arch_config(name, conf)
         t = clock()
-        params = bm.make_params(seed, conf["model"], self.cfg)
+        params = bm.make_params(family, seed, conf, self.cfg)
         jax.block_until_ready(params)
         self.weights_s = clock() - t
         profile = recompile.detect_profile()
@@ -158,9 +159,10 @@ def _memory_peak() -> int:
     return int(max(peaks, default=0))
 
 
-def run_cell(bench, name: str, seed: int, seconds: float,
+def run_cell(bench, family, name: str, seed: int, seconds: float,
              traced: bool) -> dict:
-    """One run of cell ``name``; returns the result object."""
+    """One run of cell ``name``, whose configuration is of ``family``;
+    returns the result object."""
     import jax
 
     from bench import check, flops, trace as tr, window
@@ -174,7 +176,7 @@ def run_cell(bench, name: str, seed: int, seconds: float,
     traffic = bench.generator(mix).make(mix, seed, seconds,
                                         conf["model"]["vocab_size"])
     counter = CompileCounter()
-    serving = Setup(cell["config"], conf, seed, CLOCK)
+    serving = Setup(family, cell["config"], conf, seed, CLOCK)
     log(f"bench: weights {serving.weights_s:.3f} s, deploy "
         f"{serving.deploy_s:.3f} s, warmup {serving.warmup_s:.3f} s "
         f"({serving.manifest['boot']['warmup_compiles']} programs, boot "
@@ -213,8 +215,8 @@ def run_cell(bench, name: str, seed: int, seconds: float,
 
     t = CLOCK()
     sample = check.sample(cases, seed, limits["sample_tokens"])
-    pad = check.pad_length(conf["engine"]["max_len"])
-    gaps = check.served_gaps(conf["model"], seed, sample, pad)
+    pad = check.pad_length(conf["engine"]["max_len"], family.Q_BLOCK)
+    gaps = check.served_gaps(family, conf, seed, sample, pad)
     gap = float(gaps.max()) if len(gaps) else float("nan")
     log(f"bench: reference over {len(sample)} requests, {len(gaps)} served "
         f"tokens, {CLOCK() - t:.3f} s")
@@ -243,9 +245,9 @@ def run_cell(bench, name: str, seed: int, seconds: float,
         device["busy_s"] = trace.busy_s()
         device["window_s"] = float(seconds)
     ctx = types.SimpleNamespace(
-        cell=cell, model=conf["model"], engine=conf["engine"],
-        close=float(seconds), recs=recs, prefill=prefill, trace=trace,
-        spans=spans, manifest=manifest,
+        cell=cell, conf=conf, family=family, close=float(seconds),
+        recs=recs, prefill=prefill, trace=trace, spans=spans,
+        manifest=manifest,
         peaks=None if dev.platform == "cpu" else flops.peaks(dev.device_kind))
     metrics, missing = {}, []
     for m in bench.metrics(cell, traced):
@@ -296,6 +298,11 @@ def main(argv=None, *, root=ROOT, require_tpu: bool = True,
 
     bench = harness.Bench(root)
     cell = bench.cell(args.workload)
+    try:
+        family = bench.family(bench.config(cell))
+    except FileNotFoundError as err:
+        log(f"bench: {err}")
+        return 2
     import jax
 
     devices = jax.devices()
@@ -319,7 +326,7 @@ def main(argv=None, *, root=ROOT, require_tpu: bool = True,
         return 2
     if compile_cache:
         _configure_jax()
-    out = run_cell(bench, args.workload, args.seed, args.seconds,
+    out = run_cell(bench, family, args.workload, args.seed, args.seconds,
                    bool(args.trace))
     for name, c in out["checks"].items():
         log(f"check {name}: {c['value']} (limit {c['rule']} {c['limit']})")

@@ -56,7 +56,8 @@ def main(argv=None) -> int:
     bench = harness.Bench(ROOT)
     cell = bench.cell(args.workload)
     conf, mix = bench.config(cell), bench.mix(cell)
-    serving = run.Setup(cell["config"], conf, args.seed, CLOCK)
+    serving = run.Setup(bench.family(conf), cell["config"], conf, args.seed,
+                        CLOCK)
     eng = serving.ex.engine
     best = None
     for k, rate in enumerate(sorted(float(r) for r in args.rates.split(","))):

@@ -15,8 +15,9 @@ if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
 TINY_MODEL = {
-    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
+    "model_type": "qwen2", "hidden_size": 64, "intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "num_hidden_layers": 2, "vocab_size": 256,
     "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,
     "tie_word_embeddings": True, "torch_dtype": "bfloat16",
 }

@@ -4,6 +4,7 @@ while the program passes it."""
 import pytest
 
 from bench import check
+from bench.families import qwen2
 from benchutil import TINY_LIMITS, TINY_MODEL, run_cell
 
 
@@ -59,12 +60,13 @@ def test_the_float8_control_fails_the_limit(tiny_root, monkeypatch, seed):
     seen = {}
     real = check.served_gaps
 
-    def spy(model, s, cases, pad):
+    def spy(family, conf, s, cases, pad):
         seen["cases"] = cases
-        return real(model, s, cases, pad)
+        return real(family, conf, s, cases, pad)
 
     monkeypatch.setattr(check, "served_gaps", spy)
     rc, out = run_cell(tiny_root, seed=seed)
     assert rc == 0 and out["correct"]
-    ctrl = check.control_gaps(TINY_MODEL, seed, seen["cases"], 512)
+    ctrl = check.control_gaps(qwen2, {"model": TINY_MODEL}, seed,
+                              seen["cases"], 512)
     assert ctrl.max() > TINY_LIMITS["logit_gap"]["limit"]

@@ -7,7 +7,7 @@ import pytest
 
 from bench import model as bm
 from bench import weights
-from bench.reference.qwen2 import Reference
+from bench.families import qwen2
 from benchutil import TINY_MODEL
 
 
@@ -16,8 +16,9 @@ def _program_logits(model, seed, toks, steps):
     decode steps, through the program's own model functions."""
     from repro.models import transformer
 
-    cfg = bm.arch_config("tiny", model)
-    params = bm.make_params(seed, model, cfg)
+    conf = {"model": model}
+    cfg = qwen2.arch_config("tiny", conf)
+    params = bm.make_params(qwen2, seed, conf, cfg)
     dt = jnp.dtype(cfg.activ_dtype)
     ps, nb = 16, 8
     states = transformer.init_paged_states(cfg, 1 + nb, ps, dt)
@@ -48,7 +49,8 @@ def test_reference_matches_the_program_in_f32(tied):
     got, served = _program_logits(model, 11, toks, steps=6)
     seq = np.concatenate([toks, served[:-1]]).astype(np.int32)
     want = np.arange(len(toks) - 1, len(seq))
-    ref = np.asarray(Reference(model, 11).logits([seq], [want], 512)[0])
+    ref = np.asarray(qwen2.Reference({"model": model}, 11)
+                     .logits([seq], [want], 512)[0])
     scale = np.abs(ref).max()
     assert np.abs(got - ref).max() <= 1e-4 * scale
 
@@ -58,8 +60,9 @@ def test_bf16_program_is_close_and_the_fp8_control_is_not():
     got, served = _program_logits(TINY_MODEL, 3, toks, steps=8)
     seq = np.concatenate([toks, served[:-1]]).astype(np.int32)
     want = np.arange(len(toks) - 1, len(seq))
-    ref = np.asarray(Reference(TINY_MODEL, 3).logits([seq], [want], 512)[0])
-    low = np.asarray(Reference(TINY_MODEL, 3, fp8=True)
+    conf = {"model": TINY_MODEL}
+    ref = np.asarray(qwen2.Reference(conf, 3).logits([seq], [want], 512)[0])
+    low = np.asarray(qwen2.Reference(conf, 3, fp8=True)
                      .logits([seq], [want], 512)[0])
     err_prog = np.abs(got - ref).max()
     err_low = np.abs(low - ref).max()
@@ -68,10 +71,11 @@ def test_bf16_program_is_close_and_the_fp8_control_is_not():
 
 def test_one_layer_made_alone_equals_the_stacked_tree():
     model = dict(TINY_MODEL, num_hidden_layers=3)
-    cfg = bm.arch_config("tiny", model)
-    params = bm.make_params(2**31 + 9, model, cfg)
-    alone = weights.layer_tensors(weights.seed_key(2**31 + 9), model, 2,
-                                  jnp.bfloat16)
+    conf = {"model": model}
+    cfg = qwen2.arch_config("tiny", conf)
+    params = bm.make_params(qwen2, 2**31 + 9, conf, cfg)
+    alone = qwen2.layer_tensors(weights.seed_key(2**31 + 9), model, 2,
+                                jnp.bfloat16)
     stacked = params["scan"][0]
     assert np.array_equal(np.asarray(alone["down_w"]),
                           np.asarray(stacked["ffn"]["w_down"]["w"][2]))
@@ -82,7 +86,8 @@ def test_one_layer_made_alone_equals_the_stacked_tree():
 def test_a_changed_program_layout_is_refused(monkeypatch):
     from repro.models import transformer
 
-    cfg = bm.arch_config("tiny", TINY_MODEL)
+    conf = {"model": TINY_MODEL}
+    cfg = qwen2.arch_config("tiny", conf)
     real = transformer.init_model
 
     def renamed(key, cfg):
@@ -92,5 +97,5 @@ def test_a_changed_program_layout_is_refused(monkeypatch):
 
     monkeypatch.setattr(transformer, "init_model", renamed)
     with pytest.raises(RuntimeError, match="layout differs"):
-        bm.make_params(0, TINY_MODEL, cfg)
+        bm.make_params(qwen2, 0, conf, cfg)
     assert jax.devices()
